@@ -14,8 +14,8 @@ Coding backends (ISSUE 6)
 
 * ``"numpy"``  — the byte-LUT reference (``erasure.gf.gf_matmul_np``).
 * ``"kernel"`` — the hardware path (``repro.kernels.gf256_matmul.ops.
-  gf256_coding_matmul``): the Pallas bitsliced kernel where it compiles
-  natively (TPU), the jit'd XLA LUT formulation on CPU.
+  gf256_matmul``): the Pallas bitsliced kernel where it compiles natively
+  (TPU), the jit'd XLA LUT formulation on CPU.
 * ``"auto"``   — size-based dispatch: operands at or above
   ``AUTO_KERNEL_MIN_BYTES`` (measured crossover on the reference container,
   see ``benchmarks/bench_kernels.py``) take the kernel path; tiny
@@ -165,7 +165,7 @@ class RSCode:
         if self._use_kernel(np.asarray(A), np.asarray(B)):
             from repro.kernels.gf256_matmul import ops as gf_ops
 
-            return np.asarray(gf_ops.gf256_coding_matmul(A, B))
+            return gf_ops.gf256_matmul(A, B)
         return gf_matmul_np(A, B)
 
     @staticmethod
@@ -345,9 +345,9 @@ class RSCode:
         block-diagonal product would cost G x the dense work)."""
         fuse = self.fuse_groups
         if fuse is None and self.backend != "numpy" and len(jobs) > 1:
-            from repro.kernels.gf256_matmul import ops as gf_ops
+            from repro.kernels import dispatch
 
-            fuse = gf_ops.kernel_is_native()
+            fuse = dispatch.kernel_is_native()
         if (
             not fuse
             or len(jobs) <= 1

@@ -9,15 +9,22 @@ where left-shifted-out bits vanish, so h_i depends on a *fixed 32-byte
 window*: a windowed weighted sum, data-parallel over every position i.
 ``gear()`` is an arithmetic byte mixer (no LUT — TPU-friendly).
 
-Tiling. Grid over L in blocks of BL. Each step needs bytes
-[i*BL - (W-1), (i+1)*BL); Pallas blocks cannot overlap, so the input is
-passed twice with different index maps (previous block + current block) and
-the kernel stitches the W-1-byte tail. Output: the uint32 hash stream and a
-uint8 boundary bitmap (h & mask == 0).
+Layout. The stream is viewed as ``(R, width)`` rows, ``width`` a multiple
+of the 128-lane vreg width, and the grid walks blocks of ``block_rows``
+rows (a multiple of the 32-row uint8 sublane tile, or all rows). Position
+``c`` of a row needs bytes ``c-31..c``; for ``c < 31`` some of them sit at
+the end of the PREVIOUS row. Blocks cannot overlap, so the wrapper passes
+each row's predecessor tail — the previous row's last 128 bytes, zeros for
+row 0 — as a second ``(R, 128)`` operand. In-kernel, ``pltpu.roll`` along
+the lanes shifts the gear stream by j: correct everywhere except the first
+31 lanes, which the first lane-tile recomputes with the tail spliced in
+(``lane < j`` selects the rolled tail). Every block is tile-aligned, no
+slice is unaligned, and the output is the uint32 hash stream and a uint8
+boundary bitmap (h & mask == 0).
 
 The W shifted adds are vector ALU work: ~W ops/byte with zero HBM
-re-reads — memory-bound at 1 byte/position in, 5 bytes/position out
-(bitmap-only variant: 1 byte out).
+re-reads — memory-bound at 1 byte/position in (+128/width for the tails),
+5 bytes/position out.
 """
 from __future__ import annotations
 
@@ -26,8 +33,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 WINDOW = 32
+LANES = 128
+ROW_WIDTH = 1024   # bytes per row of the 2-D view (lane multiple)
+ROW_TILE = 32      # uint8 sublane tile: block rows must be a multiple
 
 
 def gear_mix(x: jnp.ndarray) -> jnp.ndarray:
@@ -40,50 +51,78 @@ def gear_mix(x: jnp.ndarray) -> jnp.ndarray:
     return v
 
 
-def _gearhash_kernel(prev_ref, cur_ref, h_ref, b_ref, *, mask: int):
-    prev_tail = prev_ref[0, -(WINDOW - 1):]       # (W-1,) bytes of block i-1
-    cur = cur_ref[0]                              # (BL,)
-    ext = jnp.concatenate([prev_tail, cur])       # (BL + W - 1,)
-    g = gear_mix(ext)                             # (BL + W - 1,) uint32
-    bl = cur.shape[0]
-    h = jnp.zeros((bl,), dtype=jnp.uint32)
-    # h[i] = sum_j g_ext[i + (W-1) - j] << j ; j static -> unrolled adds.
-    for j in range(WINDOW):
-        h = h + (jax.lax.dynamic_slice_in_dim(g, WINDOW - 1 - j, bl) << jnp.uint32(j))
-    h_ref[0, :] = h
-    b_ref[0, :] = ((h & jnp.uint32(mask)) == 0).astype(jnp.uint8)
+def _gearhash_kernel(tail_ref, cur_ref, h_ref, b_ref, *, mask: int):
+    g = gear_mix(cur_ref[...].astype(jnp.int32))    # (BR, width) uint32
+    gt = gear_mix(tail_ref[...].astype(jnp.int32))  # (BR, 128): prev row's tail
+    g0 = g[:, :LANES]
+    lane = jax.lax.broadcasted_iota(jnp.int32, g0.shape, 1)
+    h, h0 = g, g0
+    # h[c] = sum_j g[c - j] << j; roll(x, j)[c] = x[c - j] (wrapping), and
+    # the wrapped lanes c < j of the first tile take the previous row's tail.
+    for j in range(1, WINDOW):
+        sh = jnp.uint32(j)
+        h = h + (pltpu.roll(g, j, 1) << sh)
+        h0 = h0 + (jnp.where(lane < j, pltpu.roll(gt, j, 1), pltpu.roll(g0, j, 1)) << sh)
+    m = jnp.uint32(mask)
+    h_ref[:, :LANES] = h0
+    b_ref[:, :LANES] = ((h0 & m) == 0).astype(jnp.int32).astype(jnp.uint8)
+    if g.shape[1] > LANES:
+        hr = h[:, LANES:]
+        h_ref[:, LANES:] = hr
+        b_ref[:, LANES:] = ((hr & m) == 0).astype(jnp.int32).astype(jnp.uint8)
+
+
+def layout(L: int, block_l: int) -> tuple[int, int]:
+    """``(width, block_rows)`` of the 2-D view for an L-byte stream with
+    ~``block_l`` positions per grid step; raises ``ValueError`` when L has
+    no tile-aligned view (callers pad L to a power of two >= 128)."""
+    width = min(ROW_WIDTH, L)
+    if L <= 0 or L % LANES or L % width:
+        raise ValueError(
+            f"stream length {L} must be a positive multiple of {LANES} "
+            f"(and of {ROW_WIDTH} above it)"
+        )
+    rows = L // width
+    block_rows = min(rows, max(ROW_TILE, block_l // width))
+    if rows % block_rows or (block_rows % ROW_TILE and block_rows != rows):
+        raise ValueError(
+            f"{rows} rows of {width} bytes do not tile into blocks of "
+            f"{block_rows} rows (need a multiple of {ROW_TILE})"
+        )
+    return width, block_rows
 
 
 @functools.partial(jax.jit, static_argnames=("block_l", "mask", "interpret"))
 def gearhash_pallas(
-    data: jax.Array, *, block_l: int = 4096, mask: int = 0xFFFF, interpret: bool = False
+    data: jax.Array, *, block_l: int = 1 << 17, mask: int = 0xFFFF,
+    interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """data: (L,) uint8, L % block_l == 0. Returns (hash (L,) uint32,
-    boundary bitmap (L,) uint8). Positions < W-1 hash a zero-padded window
-    (first block's "previous block" is the first block itself with its tail
-    masked to zero via index_map clamping — see below)."""
+    """data: (L,) uint8 with a tile-aligned view (see ``layout``). Returns
+    (hash (L,) uint32, boundary bitmap (L,) uint8). Positions < W-1 hash a
+    window zero-padded in byte space (row 0's tail is all zeros)."""
     L = data.shape[0]
-    assert L % block_l == 0, (L, block_l)
-    nblk = L // block_l
-    # Reshape to (nblk, BL) so block i-1 / block i are plain row indices.
-    d2 = data.reshape(nblk, block_l)
-    # A zero row is prepended so block 0's "previous" is all-zero padding.
-    d2p = jnp.concatenate([jnp.zeros((1, block_l), jnp.uint8), d2], axis=0)
+    width, block_rows = layout(L, block_l)
+    rows = L // width
+    d2 = data.reshape(rows, width)
+    tails = jnp.concatenate(
+        [jnp.zeros((1, LANES), jnp.uint8), d2[:-1, width - LANES:]], axis=0
+    )
     h, b = pl.pallas_call(
         functools.partial(_gearhash_kernel, mask=mask),
-        grid=(nblk,),
+        grid=(rows // block_rows,),
         in_specs=[
-            pl.BlockSpec((1, block_l), lambda i: (i, 0)),      # previous row of d2p
-            pl.BlockSpec((1, block_l), lambda i: (i + 1, 0)),  # current row of d2p
+            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_l), lambda i: (i, 0)),
-            pl.BlockSpec((1, block_l), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, width), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nblk, block_l), jnp.uint32),
-            jax.ShapeDtypeStruct((nblk, block_l), jnp.uint8),
+            jax.ShapeDtypeStruct((rows, width), jnp.uint32),
+            jax.ShapeDtypeStruct((rows, width), jnp.uint8),
         ],
         interpret=interpret,
-    )(d2p, d2p)
+        name="cdc_gearhash",
+    )(tails, d2)
     return h.reshape(L), b.reshape(L)

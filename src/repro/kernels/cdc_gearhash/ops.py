@@ -2,22 +2,22 @@
 
 ``split_chunks`` is what the Fragmentation Module calls: kernel-computed
 boundary candidates + a cheap host pass enforcing min/avg/max chunk sizes
-(the paper's rabin-fingerprint parameters)."""
+(the paper's rabin-fingerprint parameters).
+
+Streams are zero-padded on the host to a power-of-two length before they
+reach the device and sliced back on the host: the hash at i depends only on
+bytes i-31..i, so trailing padding leaves positions < L bit-identical, and a
+stream of mixed object sizes compiles O(log L) programs instead of one per
+length."""
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import dispatch
 from repro.kernels.cdc_gearhash.kernel import gearhash_pallas
-
-
-def _default_backend() -> str:
-    # On TPU the Pallas kernel compiles natively; on CPU the jit'd pure-jnp
-    # oracle is the fast path (interpret-mode Pallas is for validation only).
-    return "kernel" if jax.default_backend() == "tpu" else "ref"
 
 
 def _mask_for_avg(avg_size: int) -> int:
@@ -33,32 +33,43 @@ def _ref_jit(data, *, mask):
     return gearhash_ref(data, mask=mask)
 
 
+def _gearhash_device(
+    data: np.ndarray | bytes, mask: int, block_l: int, interpret: bool | None
+) -> tuple[jax.Array, jax.Array, int]:
+    """Hash and bitmap of ``data`` padded to its power-of-two bucket, still
+    on the device, and the unpadded length. ``interpret=None`` runs the
+    native kernel on TPU and the jit'd ref elsewhere (interpret-mode Pallas
+    is for validation only); ``interpret=True`` forces the interpreter."""
+    buf = np.frombuffer(data, dtype=np.uint8) if isinstance(data, (bytes, bytearray)) \
+        else np.asarray(data, dtype=np.uint8)
+    L = buf.shape[0]
+    Lp = dispatch.width_bucket(L)
+    padded = np.zeros(Lp, dtype=np.uint8)
+    padded[:L] = buf
+    if interpret is None and not dispatch.kernel_is_native():
+        h, b = _ref_jit(padded, mask=mask)
+    else:
+        h, b = gearhash_pallas(padded, block_l=block_l, mask=mask,
+                               interpret=bool(interpret))
+    return h, b, L
+
+
 def gearhash(
-    data: np.ndarray | bytes, *, mask: int = 0xFFFF, block_l: int = 4096,
+    data: np.ndarray | bytes, *, mask: int = 0xFFFF, block_l: int = 1 << 17,
     interpret: bool | None = None,
-) -> tuple[jax.Array, jax.Array]:
-    """Rolling gear hash + boundary bitmap for a byte stream.
-
-    ``interpret=True`` forces the Pallas kernel in interpret mode (test path);
-    ``interpret=None`` auto-selects: native kernel on TPU, jit'd ref on CPU.
-    """
-    if isinstance(data, (bytes, bytearray)):
-        data = np.frombuffer(bytes(data), dtype=np.uint8)
-    data = jnp.asarray(data, dtype=jnp.uint8)
-    L = data.shape[0]
-    if interpret is None and _default_backend() == "ref":
-        return _ref_jit(data, mask=mask)
-    interpret = bool(interpret) if interpret is not None else False
-    bl = min(block_l, max(128, 1 << int(np.ceil(np.log2(max(L, 1))))))
-    Lp = (L + bl - 1) // bl * bl
-    padded = jnp.pad(data, (0, Lp - L))
-    h, b = gearhash_pallas(padded, block_l=bl, mask=mask, interpret=interpret)
-    return h[:L], b[:L]
+) -> tuple[np.ndarray, np.ndarray]:
+    """Rolling gear hash + boundary bitmap for a byte stream (host arrays)."""
+    h, b, L = _gearhash_device(data, mask, block_l, interpret)
+    return np.asarray(h)[:L], np.asarray(b)[:L]
 
 
-def boundary_bitmap(data: np.ndarray | bytes, avg_size: int, **kw) -> np.ndarray:
-    h, b = gearhash(data, mask=_mask_for_avg(avg_size), **kw)
-    return np.asarray(b)
+def boundary_bitmap(
+    data: np.ndarray | bytes, avg_size: int, *, block_l: int = 1 << 17,
+    interpret: bool | None = None,
+) -> np.ndarray:
+    """Boundary candidates only: the hash stream never leaves the device."""
+    _h, b, L = _gearhash_device(data, _mask_for_avg(avg_size), block_l, interpret)
+    return np.asarray(b)[:L]
 
 
 def split_chunks(

@@ -80,7 +80,8 @@ def gf2_bitsliced_matmul(
     returns:      (m, L) uint8.
     """
     kL = b.shape[1]
-    assert kL % block_l == 0, (kL, block_l)
+    if kL % block_l:
+        raise ValueError(f"operand width {kL} is not a multiple of block_l={block_l}")
     mpad8, kpad8 = abits_padded.shape
     grid = (kL // block_l,)
     return pl.pallas_call(
@@ -95,4 +96,5 @@ def gf2_bitsliced_matmul(
         out_specs=pl.BlockSpec((m, block_l), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((m, kL), jnp.uint8),
         interpret=interpret,
+        name="gf256_matmul",
     )(abits_padded, b)

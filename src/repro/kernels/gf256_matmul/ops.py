@@ -1,13 +1,13 @@
-"""Public jit'd wrappers for the bitsliced GF(256) matmul kernel.
+"""Public host wrappers for the bitsliced GF(256) matmul kernel.
 
-``gf256_matmul(A, B)`` — drop-in GF(256) matrix product; host-side prep
-(bit-matrix expansion of the tiny A, L padding) + the Pallas kernel.
+``gf256_matmul(A, B)`` — the GF(256) matrix product the storage data path
+dispatches to (RSCode backend "kernel"/"auto", see ``repro.erasure.rs``):
+host-side prep (bit-matrix expansion of the tiny A, width padding) + the
+Pallas kernel where it compiles natively (TPU), the jit'd XLA LUT
+formulation elsewhere. ``interpret=True`` runs the kernel in the Pallas
+interpreter, a correctness harness orders of magnitude slower than either
+and never a production path.
 ``rs_encode_parity(parity_matrix, data)`` — the RS encode hot path.
-``gf256_coding_matmul(A, B)`` — what the storage data path's "kernel"/"auto"
-coding backend dispatches to (see ``repro.erasure.rs``): the Pallas kernel
-where it compiles natively (TPU), the jit'd XLA LUT formulation on CPU —
-``interpret=True`` Pallas is a correctness harness, orders of magnitude
-slower than either, and never a production path.
 
 All paths are bit-identical to ``ref.gf256_matmul_ref`` (and to the numpy
 LUT reference ``erasure.gf.gf_matmul_np``).
@@ -17,24 +17,14 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.erasure.gf import gf_matrix_to_bitmatrix
+from repro.kernels import dispatch
 from repro.kernels.gf256_matmul.kernel import _round_up, gf2_bitsliced_matmul
 
 # f32 VMEM tile is (8, 128); pad the bit-matrix to it.
 _SUBLANE, _LANE = 8, 128
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def kernel_is_native() -> bool:
-    """True when the Pallas kernel compiles for real hardware (TPU). Gates
-    production dispatch and the block-diagonal group fusion in RSCode."""
-    return jax.default_backend() == "tpu"
 
 
 def _validate_shapes(A: np.ndarray, B) -> None:
@@ -61,38 +51,6 @@ def _abits_cached(a_bytes: bytes, m: int, k: int) -> np.ndarray:
     return out
 
 
-def gf256_matmul(
-    A: np.ndarray,
-    B: np.ndarray | jax.Array,
-    *,
-    block_l: int = 2048,
-    interpret: bool | None = None,
-) -> jax.Array:
-    """GF(256) matrix product C = A (x) B. A: (m, k) uint8 (host, small);
-    B: (k, L) uint8 (device, large). Returns (m, L) uint8."""
-    if interpret is None:
-        interpret = _default_interpret()
-    A = np.asarray(A, dtype=np.uint8)
-    B = jnp.asarray(B, dtype=jnp.uint8)
-    _validate_shapes(A, B)
-    m, k = A.shape
-    L = B.shape[1]
-    if m == 0 or L == 0 or k == 0:
-        # degenerate shapes the storage path can produce (m == 0 codes,
-        # empty values): the product is an empty/zero matrix — don't hand
-        # a zero-sized grid to Pallas.
-        return jnp.zeros((m, L), dtype=jnp.uint8)
-    # Block size: shrink for small inputs (interpret-mode tests), keep
-    # lane-aligned where possible.
-    bl = min(block_l, _round_up(L, _LANE))
-    Lp = _round_up(L, bl)
-    if Lp != L:
-        B = jnp.pad(B, ((0, 0), (0, Lp - L)))
-    abits = jnp.asarray(_abits_cached(A.tobytes(), m, k))
-    out = gf2_bitsliced_matmul(abits, B, m=m, k=k, block_l=bl, interpret=interpret)
-    return out[:, :L]
-
-
 @functools.lru_cache(maxsize=1)
 def _jit_ref():
     from repro.kernels.gf256_matmul.ref import gf256_matmul_ref
@@ -100,16 +58,22 @@ def _jit_ref():
     return jax.jit(gf256_matmul_ref)
 
 
-def gf256_coding_matmul(A: np.ndarray, B: np.ndarray, *, block_l: int = 2048) -> jax.Array:
-    """GF(256) matmul as dispatched by the storage data path (RSCode
-    backend "kernel"/"auto").
+def gf256_matmul(
+    A: np.ndarray,
+    B: np.ndarray | jax.Array,
+    *,
+    block_l: int = 2048,
+    interpret: bool | None = None,
+) -> np.ndarray:
+    """GF(256) matrix product C = A (x) B as a host array. A: (m, k) uint8
+    (small); B: (k, L) uint8 (large). Returns (m, L) uint8.
 
-    TPU: the native Pallas bitsliced kernel. CPU: the jit'd XLA LUT
-    formulation — measured 3-10x the numpy byte-LUT from ~16 KiB operands on
-    the reference container (``benchmarks/bench_kernels.py``). L is bucketed
-    to powers of two (zero-pad, slice after — GF matmul is column-wise, so
-    padding columns is bit-identical) to bound jit retraces across ragged
-    batch widths to O(log L) compilations per (m, k).
+    ``interpret=None`` runs the native kernel on TPU and the jit'd ref
+    elsewhere; ``interpret=True`` forces the Pallas interpreter. L is
+    zero-padded on the host to ``dispatch.width_bucket(L)`` and the product
+    sliced back on the host — GF matmul is column-wise, so padding columns
+    is bit-identical — which bounds compiles across ragged widths to
+    O(log L) per (m, k) and keeps ragged shapes off the device.
     """
     A = np.asarray(A, dtype=np.uint8)
     B = np.asarray(B, dtype=np.uint8)
@@ -117,20 +81,26 @@ def gf256_coding_matmul(A: np.ndarray, B: np.ndarray, *, block_l: int = 2048) ->
     m, k = A.shape
     L = B.shape[1]
     if m == 0 or L == 0 or k == 0:
-        return jnp.zeros((m, L), dtype=jnp.uint8)
-    if kernel_is_native():
-        return gf256_matmul(A, B, block_l=block_l, interpret=False)
-    Lp = max(_LANE, 1 << (L - 1).bit_length())
+        # degenerate shapes the storage path can produce (m == 0 codes,
+        # empty values): the product is an empty/zero matrix — don't hand
+        # a zero-sized grid to Pallas.
+        return np.zeros((m, L), dtype=np.uint8)
+    Lp = dispatch.width_bucket(L)
     if Lp != L:
         Bp = np.zeros((k, Lp), dtype=np.uint8)
         Bp[:, :L] = B
         B = Bp
-    out = _jit_ref()(jnp.asarray(A), jnp.asarray(B))
-    return out[:, :L]
+    if interpret is None and not dispatch.kernel_is_native():
+        out = _jit_ref()(A, B)
+    else:
+        abits = _abits_cached(A.tobytes(), m, k)
+        out = gf2_bitsliced_matmul(abits, B, m=m, k=k, block_l=min(block_l, Lp),
+                                   interpret=bool(interpret))
+    return np.asarray(out)[:, :L]
 
 
 def rs_encode_parity(
     parity_matrix: np.ndarray, data: np.ndarray | jax.Array, **kw
-) -> jax.Array:
+) -> np.ndarray:
     """Parity rows for a systematic RS code: P = parity_matrix (x) data."""
     return gf256_matmul(parity_matrix, data, **kw)

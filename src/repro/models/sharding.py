@@ -68,16 +68,8 @@ class MeshCtx:
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, **kw):
-    """``jax.shard_map`` across jax versions: new jax exposes it at top level
-    with ``check_vma``; 0.4.x has ``jax.experimental.shard_map.shard_map``
-    with the ``check_rep`` spelling of the same flag."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if "check_vma" in kw:
-        kw["check_rep"] = kw.pop("check_vma")
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """``jax.shard_map`` with the mesh and specs passed by keyword."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
 def spec_with_model_on(shape: tuple[int, ...], ctx: MeshCtx, candidates: list[int]) -> tuple:

@@ -77,13 +77,13 @@ def test_backend_validation():
 # ---------------------------------------------------------- auto dispatch
 def _counting(monkeypatch):
     calls = []
-    real = gf_ops.gf256_coding_matmul
+    real = gf_ops.gf256_matmul
 
     def wrapper(A, B, **kw):
         calls.append(np.asarray(B).shape)
         return real(A, B, **kw)
 
-    monkeypatch.setattr(gf_ops, "gf256_coding_matmul", wrapper)
+    monkeypatch.setattr(gf_ops, "gf256_matmul", wrapper)
     return calls
 
 

@@ -25,7 +25,8 @@ _SCRIPT = textwrap.dedent(
                                    training_state_specs)
     from repro.train.optimizer import adamw_init
 
-    mesh = jax.make_mesh((2, 4, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 4, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     ctx = MeshCtx(mesh)
     cfg = get_arch("{arch}").reduced()
     model = build_model(cfg, max_pos=32)

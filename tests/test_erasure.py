@@ -243,18 +243,18 @@ def test_encode_bytes_batch_matches_encode_bytes(values, k, m):
 def test_encode_batch_single_kernel_call(monkeypatch):
     """Acceptance (ISSUE 1): >= 32 blocks on the kernel backend issue exactly
     ONE kernel matmul, bit-identical to per-block numpy encode. The kernel
-    backend dispatches through ``gf256_coding_matmul`` (ISSUE 6), so that is
-    the seam counted here."""
+    backend dispatches through ``gf256_matmul``, so that is the seam counted
+    here."""
     from repro.kernels.gf256_matmul import ops as gf_ops
 
     calls = []
-    real = gf_ops.gf256_coding_matmul
+    real = gf_ops.gf256_matmul
 
     def counting(A, B, **kw):
         calls.append(np.asarray(B).shape)
         return real(A, B, **kw)
 
-    monkeypatch.setattr(gf_ops, "gf256_coding_matmul", counting)
+    monkeypatch.setattr(gf_ops, "gf256_matmul", counting)
     rng = np.random.default_rng(3)
     code = RSCode(n=6, k=4, backend="kernel")
     data = rng.integers(0, 256, (32, 4, 16), dtype=np.uint8)

@@ -12,7 +12,7 @@ from repro.kernels.cdc_gearhash.ops import boundary_bitmap, gearhash, split_chun
 from repro.kernels.cdc_gearhash.ref import gearhash_ref
 
 
-@pytest.mark.parametrize("L", [32, 128, 4096, 5000, 12288])
+@pytest.mark.parametrize("L", [32, 128, 4096, 5000, 12288, 100_000])
 @pytest.mark.parametrize("mask", [0xFF, 0xFFF])
 def test_kernel_matches_ref(L, mask):
     rng = np.random.default_rng(L + mask)

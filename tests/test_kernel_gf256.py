@@ -3,11 +3,7 @@ import numpy as np
 import pytest
 
 from repro.erasure import RSCode, gf_matmul_np
-from repro.kernels.gf256_matmul.ops import (
-    gf256_coding_matmul,
-    gf256_matmul,
-    rs_encode_parity,
-)
+from repro.kernels.gf256_matmul.ops import gf256_matmul, rs_encode_parity
 from repro.kernels.gf256_matmul.ref import gf256_matmul_ref
 
 SHAPES = [
@@ -88,9 +84,9 @@ def test_shape_validation_raises_valueerror():
     with pytest.raises(ValueError):
         gf256_matmul(A, np.zeros(16, dtype=np.uint8), interpret=True)
     with pytest.raises(ValueError):
-        gf256_coding_matmul(A, np.zeros((5, 16), dtype=np.uint8))
+        gf256_matmul(A, np.zeros((5, 16), dtype=np.uint8))
     with pytest.raises(ValueError):
-        gf256_coding_matmul(np.zeros(4, dtype=np.uint8), np.zeros((4, 16), dtype=np.uint8))
+        gf256_matmul(np.zeros(4, dtype=np.uint8), np.zeros((4, 16), dtype=np.uint8))
 
 
 def test_degenerate_shapes():
@@ -101,7 +97,7 @@ def test_degenerate_shapes():
         B = np.zeros((ka, L), dtype=np.uint8)
         for fn in (
             lambda a, b: gf256_matmul(a, b, interpret=True),
-            gf256_coding_matmul,
+            gf256_matmul,
         ):
             out = np.asarray(fn(A, B))
             assert out.shape == (ma, L) and out.dtype == np.uint8
@@ -115,7 +111,7 @@ def test_coding_matmul_matches_lut():
     for L in (1, 7, 128, 1000, 5000):
         B = rng.integers(0, 256, (7, L), dtype=np.uint8)
         np.testing.assert_array_equal(
-            np.asarray(gf256_coding_matmul(A, B)), gf_matmul_np(A, B)
+            gf256_matmul(A, B), gf_matmul_np(A, B)
         )
 
 

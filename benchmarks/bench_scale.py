@@ -75,6 +75,7 @@ def scale_trial(sessions: int, fast: bool, *, seed: int = 7, files: int = 64,
     try:
         rep = gen.run(dss, via=via)
     finally:
+        dss.net.profile_protocol = False
         if freeze_gc:
             gc.enable()
             gc.unfreeze()

@@ -26,8 +26,9 @@ Deployment: the paper's §VII-D Emulab store (``configs/paper_store.EMULAB``:
             boundaries equal ``gearhash_ref``'s, and both kernels ran
 
 Every read must be byte-identical to what was written. Earlier lines are
-one JSON object per phase: wall time, bytes, kernel launches and compiles
-(write..reread are the steady phases and must compile nothing). The last
+one JSON object per phase: wall time, bytes, kernel launches and compiles,
+as ``repro.tracing`` counts them (write..reread are the steady phases and
+must compile nothing). The last
 line names the device. Any platform but the TPU is refused, and any failed
 check exits non-zero.
 """
@@ -56,64 +57,26 @@ STEADY = ("write", "read", "degraded", "degraded-write", "degraded-mixed",
 DEADLINE = 600.0
 
 
-class Meter:
-    """Kernel launches (by wrapping the two kernel entry points where the
-    store's data path looks them up) and JAX compile events. A GF(256)
-    launch whose operand has more rows than the code's k is a block-diagonal
-    fused decode of several survivor sets, counted apart as well."""
+# a GF(256) launch's counter by operand rows: more rows than the code's k is
+# a block-diagonal fused decode of several survivor sets
+GF_ROWS = "gf256.launch.rows."
 
-    def __init__(self, code_k: int) -> None:
-        self.code_k = code_k
-        self.launches = {"gf256_matmul": 0, "gf256_fused": 0, "cdc_gearhash": 0}
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        self._undo: list = []
 
-    def __enter__(self) -> "Meter":
-        import jax.monitoring
-        from repro.kernels.cdc_gearhash import ops as cdc_ops
-        from repro.kernels.gf256_matmul import ops as gf_ops
+def counters(code_k: int) -> dict:
+    """JAX compiles and kernel launches so far, from ``repro.tracing``."""
+    from repro import tracing
 
-        for mod, attr, name in ((gf_ops, "gf2_bitsliced_matmul", "gf256_matmul"),
-                                (cdc_ops, "gearhash_pallas", "cdc_gearhash")):
-            fn = getattr(mod, attr)
-            setattr(mod, attr, self._counted(name, fn))
-            self._undo.append((mod, attr, fn))
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-        return self
-
-    def __exit__(self, *exc) -> None:
-        import jax.monitoring
-
-        for mod, attr, fn in self._undo:
-            setattr(mod, attr, fn)
-        jax.monitoring.unregister_event_duration_listener(self._on_duration)
-        jax.monitoring.unregister_event_listener(self._on_event)
-
-    def _counted(self, name, fn):
-        def launch(*args, **kw):
-            self.launches[name] += 1
-            if kw.get("k", 0) > self.code_k:
-                self.launches["gf256_fused"] += 1
-            return fn(*args, **kw)
-
-        return launch
-
-    def _on_duration(self, event: str, secs: float, **_kw) -> None:
-        if event.startswith("/jax/core/compile/"):
-            self.compile_s += secs
-        if event == "/jax/core/compile/jaxpr_to_mlir_module_duration":
-            self.compiles += 1
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self) -> dict:
-        return {"compiles": self.compiles, "compile_s": self.compile_s,
-                "cache_hits": self.cache_hits, **self.launches}
+    snap = tracing.snapshot()
+    counts = snap["counts"]
+    return {
+        "compiles": sum(snap["compiles"].values()),
+        "compile_s": sum(snap["compile_s"].values()),
+        "cache_hits": counts.get("compile_cache_hits", 0),
+        "gf256_matmul": counts.get("gf256.launch", 0),
+        "gf256_fused": sum(n for name, n in counts.items()
+                           if name.startswith(GF_ROWS) and int(name[len(GF_ROWS):]) > code_k),
+        "cdc_gearhash": counts.get("cdc_kernel.launch", 0),
+    }
 
 
 def check(ok: bool, what: str) -> None:
@@ -121,13 +84,13 @@ def check(ok: bool, what: str) -> None:
         raise RuntimeError(f"chip_smoke check failed: {what}")
 
 
-def run_phase(meter: Meter, name: str, fn, report: list) -> object:
-    """Run one phase; print and keep its wall time and meter deltas."""
-    before = meter.snapshot()
+def run_phase(code_k: int, name: str, fn, report: list) -> object:
+    """Run one phase; print and keep its wall time and counter deltas."""
+    before = counters(code_k)
     t0 = time.perf_counter()
     out = fn()
     row = {"phase": name, "wall_s": time.perf_counter() - t0}
-    after = meter.snapshot()
+    after = counters(code_k)
     row.update({k: after[k] - before[k] for k in after})
     if isinstance(out, dict):
         row.update(out)
@@ -285,47 +248,60 @@ def check_cdc(data: bytes, avg_block: int) -> dict:
 
 
 def run(payloads: dict, late: dict, *, seed: int, min_block: int,
-        avg_block: int, max_block: int, cdc_fid: str, meter: Meter) -> list[dict]:
-    """Every phase after the device check; returns the per-phase rows.
-    ``late`` objects are written while servers are down."""
+        avg_block: int, max_block: int, cdc_fid: str) -> list[dict]:
+    """Every phase after the device check, with the tracer on; returns the
+    per-phase rows. ``late`` objects are written while servers are down."""
+    from repro import tracing
+
+    was = tracing.enabled()
+    tracing.enable(True)
+    try:
+        return _phases(payloads, late, seed=seed, min_block=min_block,
+                       avg_block=avg_block, max_block=max_block, cdc_fid=cdc_fid)
+    finally:
+        tracing.enable(was)
+
+
+def _phases(payloads: dict, late: dict, *, seed: int, min_block: int,
+            avg_block: int, max_block: int, cdc_fid: str) -> list[dict]:
     report: list[dict] = []
     dss = build_store(seed=seed, min_block=min_block, avg_block=avg_block,
                       max_block=max_block)
-    check(meter.code_k == dss.c0.k, "the meter counts fused launches at the code's k")
-    n_down = (dss.c0.n - dss.c0.k) // 2
+    k = dss.c0.k
+    n_down = (dss.c0.n - k) // 2
     everything = {**payloads, **late}
-    run_phase(meter, "warm-up", lambda: warm_up(
+    run_phase(k, "warm-up", lambda: warm_up(
         dss, everything, min_block=min_block, avg_block=avg_block,
         n_down=n_down), report)
-    run_phase(meter, "write", lambda: write_all(dss, payloads), report)
-    run_phase(meter, "read", lambda: read_all(dss, payloads, "read"), report)
+    run_phase(k, "write", lambda: write_all(dss, payloads), report)
+    run_phase(k, "read", lambda: read_all(dss, payloads, "read"), report)
     down = down_servers(dss, n_down)
     dss.crash_servers(down)
-    run_phase(meter, "degraded", lambda: {
+    run_phase(k, "degraded", lambda: {
         "down": down, **read_all(dss, payloads, "degraded")}, report)
-    run_phase(meter, "degraded-write", lambda: write_all(dss, late), report)
+    run_phase(k, "degraded-write", lambda: write_all(dss, late), report)
     # the crashed systematic holder restarts with its disk and the next one
     # fails, so objects written before and during the outage lost different
     # fragments, and each reader's batch mixes the two
     swapped = [dss.c0.servers[1]] + down[1:]
     dss.recover_servers(down[:1])
     dss.crash_servers(swapped[:1])
-    run_phase(meter, "degraded-mixed", lambda: {
+    run_phase(k, "degraded-mixed", lambda: {
         "down": swapped, **read_all(dss, everything, "mixed")}, report)
     check(report[-1]["gf256_fused"] > 0,
           "a read batch decoded two survivor sets in one fused launch")
-    run_phase(meter, "repair", lambda: repair(dss, swapped), report)
-    run_phase(meter, "reread", lambda: read_all(dss, everything, "reread"), report)
+    run_phase(k, "repair", lambda: repair(dss, swapped), report)
+    run_phase(k, "reread", lambda: read_all(dss, everything, "reread"), report)
 
     def final_checks() -> dict:
         hist = dss.check_history()
         out = {"history": hist, **check_parity(dss, cdc_fid),
                **check_cdc(payloads[cdc_fid], avg_block)}
-        check(meter.launches["gf256_matmul"] > 0, "the GF(256) kernel ran")
-        check(meter.launches["cdc_gearhash"] > 0, "the CDC kernel ran")
+        check(sum(r["gf256_matmul"] for r in report) > 0, "the GF(256) kernel ran")
+        check(sum(r["cdc_gearhash"] for r in report) > 0, "the CDC kernel ran")
         return out
 
-    run_phase(meter, "check", final_checks, report)
+    run_phase(k, "check", final_checks, report)
     steady = sum(r["compiles"] for r in report if r["phase"] in STEADY)
     check(steady == 0, f"steady phases compiled {steady} programs")
     return report
@@ -339,7 +315,6 @@ def main(argv=None) -> int:
     import jax
 
     from repro.compile_cache import enable_compile_cache
-    from repro.configs.paper_store import EMULAB
 
     dev = check_device()
     cache_dir = enable_compile_cache()
@@ -352,16 +327,18 @@ def main(argv=None) -> int:
                       "cut": None, "compile_cache": cache_dir, **BLOCKS}),
           flush=True)
     cdc_fid = next(f for f, v in payloads.items() if len(v) == CDC_CHECK_SIZE)
-    with Meter(code_k=EMULAB.n_servers - EMULAB.parity_m) as meter:
-        report = run(payloads, late, seed=args.seed, cdc_fid=cdc_fid, meter=meter,
-                     **BLOCKS)
+    report = run(payloads, late, seed=args.seed, cdc_fid=cdc_fid, **BLOCKS)
     stats = dev.memory_stats() or {}
+
+    def total(key: str):
+        return sum(r[key] for r in report)
+
     print(json.dumps({
         "phase": "total",
         "steady_wall_s": sum(r["wall_s"] for r in report if r["phase"] in STEADY),
         "steady_compiles": sum(r["compiles"] for r in report if r["phase"] in STEADY),
-        "compile_s": meter.compile_s, "cache_hits": meter.cache_hits,
-        "launches": meter.launches,
+        "compile_s": total("compile_s"), "cache_hits": total("cache_hits"),
+        "launches": {key: total(key) for key in ("gf256_matmul", "gf256_fused", "cdc_gearhash")},
         "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
     }), flush=True)
     print(json.dumps({"ok": True, "device": {

@@ -36,6 +36,7 @@ import pickle
 from difflib import SequenceMatcher
 from typing import Generator
 
+from repro import tracing
 from repro.core.tags import Config, OpRecord
 from repro.kernels.cdc_gearhash.ops import split_chunks
 from repro.net.sim import Join, Sleep
@@ -302,9 +303,10 @@ class FragmentationModule:
         )
         if chunks == [b""]:
             chunks = []
-        old_hashes = [_h(d) for _, d in live]
-        new_hashes = [_h(c) for c in chunks]
-        ops = SequenceMatcher(None, old_hashes, new_hashes, autojunk=False).get_opcodes()
+        with tracing.span("block_hash", nbytes=len(content)):
+            old_hashes = [_h(d) for _, d in live]
+            new_hashes = [_h(c) for c in chunks]
+            ops = SequenceMatcher(None, old_hashes, new_hashes, autojunk=False).get_opcodes()
         # --- build the target block list -----------------------------------
         target: list[tuple[str | None, bytes]] = []  # (bid | None=new, data)
         for op, i1, i2, j1, j2 in ops:

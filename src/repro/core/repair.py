@@ -42,12 +42,11 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Any, Generator
-import zlib
 
 import numpy as np
 
 from repro.core.tags import TAG0, Config, OpRecord, Tag
-from repro.erasure.rs import RSCode, element_crc_ok
+from repro.erasure.rs import RSCode, element_crc_ok, fragment_crc
 from repro.net.sim import Join, RPC, Sleep
 
 
@@ -255,7 +254,7 @@ class RepairController:
                 obj,
                 self.cfg_idx,
                 t_star,
-                (fb, orig, zlib.crc32(fb)),
+                (fb, orig, fragment_crc(fb)),
                 self.config.delta,
             )
             for sid, fb in zip(missing, frag_bytes)

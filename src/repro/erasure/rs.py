@@ -44,6 +44,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import tracing
 from repro.erasure.gf import gf_matmul_np
 from repro.erasure.matrix import cauchy_parity_matrix, gf_invert_matrix
 
@@ -70,7 +71,13 @@ def element_crc_ok(elem) -> bool:
     """
     if not isinstance(elem, tuple) or len(elem) < 3 or elem[2] is None:
         return True
-    return zlib.crc32(elem[0]) == elem[2]
+    return fragment_crc(elem[0]) == elem[2]
+
+
+def fragment_crc(data: bytes) -> int:
+    """CRC-32 of a fragment's bytes, as the ``crc`` span (with its bytes)."""
+    with tracing.span("crc", nbytes=len(data)):
+        return zlib.crc32(data)
 
 
 def bytes_to_rows(data: bytes, k: int) -> tuple[np.ndarray, int]:
@@ -231,11 +238,12 @@ class RSCode:
         Returns (len(target_idxs), L) rows in target order. Used by the
         repair controller, which typically replaces every fragment a set of
         recovered servers lost at once."""
-        data = self.decode(fragments, indices)
-        if not target_idxs:
-            return np.zeros((0, data.shape[1]), dtype=np.uint8)
-        gen = np.stack([self.generator_row(i) for i in target_idxs], axis=0)
-        return np.asarray(self._matmul(gen, data))
+        with tracing.span("rs.decode"):
+            data = self.decode(fragments, indices)
+            if not target_idxs:
+                return np.zeros((0, data.shape[1]), dtype=np.uint8)
+            gen = np.stack([self.generator_row(i) for i in target_idxs], axis=0)
+            return np.asarray(self._matmul(gen, data))
 
     # -- batched coding (single fused GF(256) matmul over many blocks) -------
     def encode_batch(self, data: np.ndarray) -> np.ndarray:
@@ -303,28 +311,32 @@ class RSCode:
         bytes (the integrity tags the EC DAP ships inside coded elements)."""
         if not values:
             return []
-        rows: list[np.ndarray] = []
-        origs: list[int] = []
-        for v in values:
-            r, o = bytes_to_rows(v, self.k)
-            rows.append(r)
-            origs.append(o)
-        if self.m:
-            flat = rows[0] if len(rows) == 1 else np.concatenate(rows, axis=1)
-            parity = np.asarray(self._matmul(self._parity, flat))
-        out = []
-        off = 0
-        for b, r in enumerate(rows):
-            lb = r.shape[1]
-            frags = [r[i].tobytes() for i in range(self.k)]
+        with tracing.span("rs.encode"):
+            with tracing.span("rs.pack"):
+                rows: list[np.ndarray] = []
+                origs: list[int] = []
+                for v in values:
+                    r, o = bytes_to_rows(v, self.k)
+                    rows.append(r)
+                    origs.append(o)
+                if self.m:
+                    flat = rows[0] if len(rows) == 1 else np.concatenate(rows, axis=1)
             if self.m:
-                frags += [parity[j, off : off + lb].tobytes() for j in range(self.m)]
-                off += lb
-            if with_crc:
-                out.append((frags, origs[b], [zlib.crc32(f) for f in frags]))
-            else:
-                out.append((frags, origs[b]))
-        return out
+                parity = np.asarray(self._matmul(self._parity, flat))
+            out = []
+            off = 0
+            with tracing.span("rs.unpack"):
+                for b, r in enumerate(rows):
+                    lb = r.shape[1]
+                    frags = [r[i].tobytes() for i in range(self.k)]
+                    if self.m:
+                        frags += [parity[j, off : off + lb].tobytes() for j in range(self.m)]
+                        off += lb
+                    if with_crc:
+                        out.append((frags, origs[b], [fragment_crc(f) for f in frags]))
+                    else:
+                        out.append((frags, origs[b]))
+            return out
 
     def _choose_idxs(self, fragments: dict) -> tuple[int, ...]:
         """The k-subset of fragment indices to decode from: the all-systematic
@@ -357,16 +369,18 @@ class RSCode:
             return [np.asarray(self._matmul(dec, flat)) for dec, flat in jobs]
         k, G = self.k, len(jobs)
         wmax = max(flat.shape[1] for _, flat in jobs)
-        A = np.zeros((G * k, G * k), dtype=np.uint8)
-        B = np.zeros((G * k, wmax), dtype=np.uint8)
-        for g, (dec, flat) in enumerate(jobs):
-            A[g * k : (g + 1) * k, g * k : (g + 1) * k] = dec
-            B[g * k : (g + 1) * k, : flat.shape[1]] = flat
+        with tracing.span("rs.pack"):
+            A = np.zeros((G * k, G * k), dtype=np.uint8)
+            B = np.zeros((G * k, wmax), dtype=np.uint8)
+            for g, (dec, flat) in enumerate(jobs):
+                A[g * k : (g + 1) * k, g * k : (g + 1) * k] = dec
+                B[g * k : (g + 1) * k, : flat.shape[1]] = flat
         out = np.asarray(self._matmul(A, B))
-        return [
-            np.ascontiguousarray(out[g * k : (g + 1) * k, : flat.shape[1]])
-            for g, (_, flat) in enumerate(jobs)
-        ]
+        with tracing.span("rs.unpack"):
+            return [
+                np.ascontiguousarray(out[g * k : (g + 1) * k, : flat.shape[1]])
+                for g, (_, flat) in enumerate(jobs)
+            ]
 
     def decode_bytes_batch(self, items: list[tuple]) -> list[bytes]:
         """Decode many byte values with as few GF(256) matmuls as possible.
@@ -387,55 +401,60 @@ class RSCode:
         out: list[bytes | None] = [None] * len(items)
         sys_idxs = tuple(range(self.k))
         groups: dict[tuple[int, ...], list[tuple[int, dict, int, int]]] = {}
-        for pos, item in enumerate(items):
-            fragments, orig = item[0], item[1]
-            crcs = item[2] if len(item) > 2 else None
-            idxs = self._choose_idxs(fragments)
-            L = len(fragments[idxs[0]])
-            for i in idxs:
-                if len(fragments[i]) != L:
+        with tracing.span("rs.decode"):
+            for pos, item in enumerate(items):
+                fragments, orig = item[0], item[1]
+                crcs = item[2] if len(item) > 2 else None
+                idxs = self._choose_idxs(fragments)
+                L = len(fragments[idxs[0]])
+                for i in idxs:
+                    if len(fragments[i]) != L:
+                        raise ValueError(
+                            f"fragment length mismatch in item {pos}: index {i} "
+                            f"has {len(fragments[i])} bytes, index {idxs[0]} has {L}"
+                        )
+                    if (
+                        crcs is not None
+                        and crcs.get(i) is not None
+                        and fragment_crc(fragments[i]) != crcs[i]
+                    ):
+                        raise ValueError(
+                            f"fragment {i} of item {pos} failed its checksum"
+                        )
+                if self.k * L < orig:
                     raise ValueError(
-                        f"fragment length mismatch in item {pos}: index {i} "
-                        f"has {len(fragments[i])} bytes, index {idxs[0]} has {L}"
+                        f"item {pos}: {self.k} fragments of {L} bytes cannot hold "
+                        f"a {orig}-byte value"
                     )
-                if (
-                    crcs is not None
-                    and crcs.get(i) is not None
-                    and zlib.crc32(fragments[i]) != crcs[i]
-                ):
-                    raise ValueError(
-                        f"fragment {i} of item {pos} failed its checksum"
-                    )
-            if self.k * L < orig:
-                raise ValueError(
-                    f"item {pos}: {self.k} fragments of {L} bytes cannot hold "
-                    f"a {orig}-byte value"
-                )
-            if idxs == sys_idxs:
-                # systematic fast path: the data rows ARE the fragments
-                out[pos] = b"".join(bytes(fragments[i]) for i in idxs)[:orig]
-            else:
-                groups.setdefault(idxs, []).append((pos, fragments, L, orig))
-        jobs: list[tuple[np.ndarray, np.ndarray]] = []
-        metas: list[list[tuple[int, int, int, int]]] = []
-        for idxs, members in groups.items():
-            W = sum(L for _, _, L, _ in members)
-            flat = np.zeros((self.k, W), dtype=np.uint8)
-            meta: list[tuple[int, int, int, int]] = []
-            off = 0
-            for pos, fragments, L, orig in members:
-                for r, i in enumerate(idxs):
-                    flat[r, off : off + L] = np.frombuffer(
-                        fragments[i], dtype=np.uint8
-                    )
-                meta.append((pos, off, L, orig))
-                off += L
-            jobs.append((_decoder_cached(self.n, self.k, idxs), flat))
-            metas.append(meta)
-        for data, meta in zip(self._decode_flats(jobs), metas):
-            for pos, off, L, orig in meta:
-                rows = np.ascontiguousarray(data[:, off : off + L])
-                out[pos] = rows_to_bytes(rows, orig)
+                if idxs == sys_idxs:
+                    # systematic fast path: the data rows ARE the fragments
+                    with tracing.span("rs.unpack"):
+                        out[pos] = b"".join(bytes(fragments[i]) for i in idxs)[:orig]
+                else:
+                    groups.setdefault(idxs, []).append((pos, fragments, L, orig))
+            jobs: list[tuple[np.ndarray, np.ndarray]] = []
+            metas: list[list[tuple[int, int, int, int]]] = []
+            with tracing.span("rs.pack"):
+                for idxs, members in groups.items():
+                    W = sum(L for _, _, L, _ in members)
+                    flat = np.zeros((self.k, W), dtype=np.uint8)
+                    meta: list[tuple[int, int, int, int]] = []
+                    off = 0
+                    for pos, fragments, L, orig in members:
+                        for r, i in enumerate(idxs):
+                            flat[r, off : off + L] = np.frombuffer(
+                                fragments[i], dtype=np.uint8
+                            )
+                        meta.append((pos, off, L, orig))
+                        off += L
+                    jobs.append((_decoder_cached(self.n, self.k, idxs), flat))
+                    metas.append(meta)
+            decoded = self._decode_flats(jobs)
+            with tracing.span("rs.unpack"):
+                for data, meta in zip(decoded, metas):
+                    for pos, off, L, orig in meta:
+                        rows = np.ascontiguousarray(data[:, off : off + L])
+                        out[pos] = rows_to_bytes(rows, orig)
         return out  # type: ignore[return-value]
 
     def decode_bytes(

@@ -8,7 +8,14 @@ Streams are zero-padded on the host to a power-of-two length before they
 reach the device and sliced back on the host: the hash at i depends only on
 bytes i-31..i, so trailing padding leaves positions < L bit-identical, and a
 stream of mixed object sizes compiles O(log L) programs instead of one per
-length."""
+length.
+
+The host steps are ``repro.tracing`` spans: ``cdc_kernel.pad``, ``.put``
+(the stream to the device), ``.run`` (launch to ready) and ``.get`` (a
+result back). Only while the tracer is on is the stream put on the device
+before the launch, and do ``put`` and ``run`` end by waiting for it; off,
+the launch moves the stream as it always has. ``split_chunks`` is the
+``cdc`` span, whose self time is the min/max walk and the chunk slicing."""
 from __future__ import annotations
 
 import functools
@@ -16,6 +23,7 @@ import functools
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.kernels import dispatch
 from repro.kernels.cdc_gearhash.kernel import gearhash_pallas
 
@@ -44,14 +52,35 @@ def _gearhash_device(
         else np.asarray(data, dtype=np.uint8)
     L = buf.shape[0]
     Lp = dispatch.width_bucket(L)
-    padded = np.zeros(Lp, dtype=np.uint8)
-    padded[:L] = buf
-    if interpret is None and not dispatch.kernel_is_native():
-        h, b = _ref_jit(padded, mask=mask)
-    else:
-        h, b = gearhash_pallas(padded, block_l=block_l, mask=mask,
-                               interpret=bool(interpret))
+    traced = tracing.enabled()
+    with tracing.span("cdc_kernel.pad", nbytes=Lp):
+        padded = np.zeros(Lp, dtype=np.uint8)
+        padded[:L] = buf
+    if traced:
+        with tracing.span("cdc_kernel.put", nbytes=Lp):
+            padded = jax.device_put(padded).block_until_ready()
+    with tracing.span("cdc_kernel.run"):
+        if interpret is None and not dispatch.kernel_is_native():
+            h, b = _ref_jit(padded, mask=mask)
+        else:
+            h, b = gearhash_pallas(padded, block_l=block_l, mask=mask,
+                                   interpret=bool(interpret))
+        if traced:
+            b.block_until_ready()
+    if traced:
+        tracing.count("cdc_kernel.launch")
+        tracing.count("cdc_kernel.bytes_in", L)
+        tracing.count("cdc_kernel.bytes_in_padded", Lp)
     return h, b, L
+
+
+def _fetch(x: jax.Array, L: int) -> np.ndarray:
+    """A device result back on the host (the ``cdc_kernel.get`` span), cut
+    to the unpadded length."""
+    with tracing.span("cdc_kernel.get", nbytes=x.nbytes):
+        host = np.asarray(x)
+    tracing.count("cdc_kernel.bytes_out", x.nbytes)
+    return host[:L]
 
 
 def gearhash(
@@ -60,7 +89,7 @@ def gearhash(
 ) -> tuple[np.ndarray, np.ndarray]:
     """Rolling gear hash + boundary bitmap for a byte stream (host arrays)."""
     h, b, L = _gearhash_device(data, mask, block_l, interpret)
-    return np.asarray(h)[:L], np.asarray(b)[:L]
+    return _fetch(h, L), _fetch(b, L)
 
 
 def boundary_bitmap(
@@ -69,7 +98,7 @@ def boundary_bitmap(
 ) -> np.ndarray:
     """Boundary candidates only: the hash stream never leaves the device."""
     _h, b, L = _gearhash_device(data, _mask_for_avg(avg_size), block_l, interpret)
-    return np.asarray(b)[:L]
+    return _fetch(b, L)
 
 
 def split_chunks(
@@ -88,23 +117,24 @@ def split_chunks(
     """
     if not data:
         return [b""]
-    bitmap = boundary_bitmap(data, avg_size, interpret=interpret)
-    cand = np.nonzero(bitmap)[0]
-    chunks: list[bytes] = []
-    start = 0
-    L = len(data)
-    ci = 0
-    while start < L:
-        lo = start + min_size
-        hi = start + max_size
-        # first candidate >= lo (strictly inside the chunk) and < hi
-        while ci < len(cand) and cand[ci] < lo:
-            ci += 1
-        if ci < len(cand) and cand[ci] < hi and cand[ci] + 1 < L:
-            end = int(cand[ci]) + 1  # boundary position is *inclusive* end
-            ci += 1
-        else:
-            end = min(hi, L)
-        chunks.append(data[start:end])
-        start = end
+    with tracing.span("cdc", nbytes=len(data)):
+        bitmap = boundary_bitmap(data, avg_size, interpret=interpret)
+        cand = np.nonzero(bitmap)[0]
+        chunks: list[bytes] = []
+        start = 0
+        L = len(data)
+        ci = 0
+        while start < L:
+            lo = start + min_size
+            hi = start + max_size
+            # first candidate >= lo (strictly inside the chunk) and < hi
+            while ci < len(cand) and cand[ci] < lo:
+                ci += 1
+            if ci < len(cand) and cand[ci] < hi and cand[ci] + 1 < L:
+                end = int(cand[ci]) + 1  # boundary position is *inclusive* end
+                ci += 1
+            else:
+                end = min(hi, L)
+            chunks.append(data[start:end])
+            start = end
     return chunks

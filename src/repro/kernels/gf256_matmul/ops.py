@@ -19,6 +19,7 @@ import functools
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.erasure.gf import gf_matrix_to_bitmatrix
 from repro.kernels import dispatch
 from repro.kernels.gf256_matmul.kernel import _round_up, gf2_bitsliced_matmul
@@ -74,6 +75,17 @@ def gf256_matmul(
     sliced back on the host — GF matmul is column-wise, so padding columns
     is bit-identical — which bounds compiles across ragged widths to
     O(log L) per (m, k) and keeps ragged shapes off the device.
+
+    Each host step is a ``repro.tracing`` span: ``gf256.pad`` (to the
+    bucket), ``gf256.put`` (the operand to the device), ``gf256.run``
+    (launch to ready), ``gf256.get`` (the product back) and ``gf256.slice``
+    (back to L). Only while the tracer is on is the operand put on the
+    device before the launch, and do ``put`` and ``run`` end by waiting
+    for it, so each step is timed alone; the path already waited in
+    ``get``, so no wait moves. Off, the launch moves the operand as it
+    always has. Launches are counted by operand rows (more than the code's
+    k: a block-diagonal fused decode), with the bytes sent unpadded and
+    padded and the bytes fetched.
     """
     A = np.asarray(A, dtype=np.uint8)
     B = np.asarray(B, dtype=np.uint8)
@@ -86,17 +98,35 @@ def gf256_matmul(
         # a zero-sized grid to Pallas.
         return np.zeros((m, L), dtype=np.uint8)
     Lp = dispatch.width_bucket(L)
-    if Lp != L:
-        Bp = np.zeros((k, Lp), dtype=np.uint8)
-        Bp[:, :L] = B
-        B = Bp
-    if interpret is None and not dispatch.kernel_is_native():
-        out = _jit_ref()(A, B)
-    else:
-        abits = _abits_cached(A.tobytes(), m, k)
-        out = gf2_bitsliced_matmul(abits, B, m=m, k=k, block_l=min(block_l, Lp),
-                                   interpret=bool(interpret))
-    return np.asarray(out)[:, :L]
+    traced = tracing.enabled()
+    with tracing.span("gf256.pad", nbytes=k * Lp):
+        if Lp != L:
+            Bp = np.zeros((k, Lp), dtype=np.uint8)
+            Bp[:, :L] = B
+            B = Bp
+    if traced:
+        with tracing.span("gf256.put", nbytes=k * Lp):
+            B = jax.device_put(B).block_until_ready()
+    with tracing.span("gf256.run"):
+        if interpret is None and not dispatch.kernel_is_native():
+            out = _jit_ref()(A, B)
+        else:
+            abits = _abits_cached(A.tobytes(), m, k)
+            out = gf2_bitsliced_matmul(abits, B, m=m, k=k, block_l=min(block_l, Lp),
+                                       interpret=bool(interpret))
+        if traced:
+            out.block_until_ready()
+    with tracing.span("gf256.get", nbytes=m * Lp):
+        host = np.asarray(out)
+    with tracing.span("gf256.slice"):
+        host = host[:, :L]
+    if traced:
+        tracing.count("gf256.launch")
+        tracing.count(f"gf256.launch.rows.{k}")
+        tracing.count("gf256.bytes_in", k * L)
+        tracing.count("gf256.bytes_in_padded", k * Lp)
+        tracing.count("gf256.bytes_out", m * Lp)
+    return host
 
 
 def rs_encode_parity(

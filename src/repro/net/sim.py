@@ -87,11 +87,11 @@ import itertools
 import math
 from dataclasses import dataclass
 from functools import partial
-from time import perf_counter  # protocol-lint: allow-determinism (profile_protocol wall split only; virtual time never reads it)
 from typing import Any, Callable, Generator
 
 import numpy as np
 
+from repro import tracing
 from repro.net.codec import CodecError, SizingMemo, try_wire_size
 
 
@@ -347,6 +347,23 @@ class Server:
         Durable protocol state (tags, blocks, configs) stays. Base: no-op."""
 
 
+def _handle(srv: Server, fut: OpFuture, msg: Any) -> Any:
+    """``srv.handle`` on behalf of ``fut``'s client: the ``handle`` span,
+    counted by message type while the tracer is on."""
+    if not tracing.enabled():
+        return srv.handle(fut.client, msg)
+    typed = isinstance(msg, tuple) and msg and isinstance(msg[0], str)
+    tracing.count("handle." + (msg[0] if typed else type(msg).__name__))
+    with tracing.span("handle", op=fut.op_id):
+        return srv.handle(fut.client, msg)
+
+
+def _framed(obj: Any) -> int:
+    """``msg_wire_size`` as the ``frame`` span (the legacy engine's sizing)."""
+    with tracing.span("frame"):
+        return msg_wire_size(obj)
+
+
 class _RpcState:
     """Shared per-RPC bookkeeping for both send paths: reply collection,
     quorum resume, and ``need="alive"`` abandonment."""
@@ -519,12 +536,7 @@ class _FanOut:
         rt = net.race_tracker
         if rt is not None:
             rt.before_handle(sid, state)
-        if net.profile_protocol:
-            t0 = perf_counter()
-            reply = srv.handle(state.fut.client, msg)
-            net.protocol_time += perf_counter() - t0
-        else:
-            reply = srv.handle(state.fut.client, msg)
+        reply = _handle(srv, state.fut, msg)
         if rt is not None:
             rt.after_handle(sid)
         if self.dups is not None and self.dups[j]:
@@ -658,14 +670,9 @@ class Network:
         # is resolved once per distinct tuple (identity-keyed, tuple pinned;
         # invalidated when topology grows). Lists are never cached.
         self._dest_cache: dict[int, tuple] = {}
-        # opt-in wall-clock split for benchmarks: with ``profile_protocol``
-        # set, seconds spent inside protocol code — op-generator bodies and
-        # ``Server.handle`` — accumulate here, so a driver can report
-        # *driver* time (wall minus protocol) for the engine comparison
-        # ISSUE 7 is about. Off by default: two perf_counter() calls per
-        # event are noise the normal path shouldn't pay.
-        self.profile_protocol = False
-        self.protocol_time = 0.0
+        # ``protocol_time`` counts from here (or from its last reset); see
+        # the property.
+        self._protocol_base = tracing.protocol_s()
         # optional runtime invariant observer (repro.analysis.sanitizer),
         # attached via ProtocolSanitizer.attach() behind DSSParams.sanitize /
         # REPRO_SANITIZE=1. Pure observer: it draws no randomness and
@@ -681,6 +688,31 @@ class Network:
         # observer fed at RPC issue / arrival handle / counted reply delivery
         # plus the tracked-map mutation hooks in core/server.py.
         self.race_tracker = None
+
+    # -- wall-clock split (repro.tracing) --------------------------------------
+    @property
+    def profile_protocol(self) -> bool:
+        """Whether the process-wide tracer is on: setting it switches the
+        tracer (``repro.tracing``), which times protocol code, framing,
+        coding and the kernel wrappers for a benchmark's wall-clock split."""
+        return tracing.enabled()
+
+    @profile_protocol.setter
+    def profile_protocol(self, on: bool) -> None:
+        tracing.enable(on)
+
+    @property
+    def protocol_time(self) -> float:
+        """Wall seconds in protocol code — op-generator bodies (the
+        outermost ``step`` spans) and ``Server.handle`` (``handle``) — while
+        the tracer was on, since this network was made or this was last set.
+        The tracer is process-wide, so this counts every network in the
+        process; assigning a value resets the count to it."""
+        return tracing.protocol_s() - self._protocol_base
+
+    @protocol_time.setter
+    def protocol_time(self, value: float) -> None:
+        self._protocol_base = tracing.protocol_s() - value
 
     # -- topology ------------------------------------------------------------
     def add_server(self, server: Server) -> None:
@@ -953,10 +985,11 @@ class Network:
         """Memoized ``msg_wire_size`` (fast path): codec frame size with
         immutable subtrees cached, ``nbytes`` heuristic outside the
         vocabulary."""
-        try:
-            return self._sizer.wire_size(obj)
-        except CodecError:
-            return nbytes(obj)
+        with tracing.span("frame"):
+            try:
+                return self._sizer.wire_size(obj)
+            except CodecError:
+                return nbytes(obj)
 
     # -- op driving ------------------------------------------------------------
     def spawn(
@@ -994,9 +1027,6 @@ class Network:
         on_done: Callable[[OpFuture], None] | None,
         exc: BaseException | None = None,
     ) -> None:
-        prof = self.profile_protocol
-        if prof:
-            t0 = perf_counter()
         try:
             # ``exc`` (ISSUE 10): a typed failure — RpcTimeout from the
             # deadline machinery — is THROWN into the generator at its
@@ -1004,18 +1034,15 @@ class Network:
             # yield again (backoff Sleep, then a fresh attempt); the Session
             # tier's _instrumented wrapper catches whatever escapes and fails
             # the OpFuture typed instead of letting it crash the event loop.
-            effect = gen.throw(exc) if exc is not None else gen.send(send_value)
+            with tracing.span("step", op=fut.op_id):
+                effect = gen.throw(exc) if exc is not None else gen.send(send_value)
         except StopIteration as stop:
-            if prof:
-                self.protocol_time += perf_counter() - t0
             fut.done = True
             fut.end = self.now
             fut.result = stop.value
             if on_done is not None:
                 on_done(fut)
             return
-        if prof:
-            self.protocol_time += perf_counter() - t0
         if isinstance(effect, Sleep):
             self.schedule(
                 effect.duration,
@@ -1416,7 +1443,7 @@ class Network:
             dup = [bool(self._dup_rng.random() < dp) for _ in range(B)]
         else:
             dup = None
-        shared = msg_wire_size(rpc.msg) if rpc.per_dest is None else None
+        shared = _framed(rpc.msg) if rpc.per_dest is None else None
         client = state.fut.client
         src_i = state.src_i
         gray = self._gray
@@ -1424,7 +1451,7 @@ class Network:
         dropped_sids: list[str] = []
         for j, (sid, srv) in enumerate(pairs):
             msg = rpc.msg if rpc.per_dest is None else rpc.per_dest[sid]
-            size = shared if shared is not None else msg_wire_size(msg)
+            size = shared if shared is not None else _framed(msg)
             self.msg_count += 1
             self.bytes_sent += size
             self._acct_add(state.acct, 0, 1, size)
@@ -1463,12 +1490,7 @@ class Network:
                 rt = self.race_tracker
                 if rt is not None:
                     rt.before_handle(sid, state)
-                if self.profile_protocol:
-                    t0 = perf_counter()
-                    reply = srv.handle(client, msg)
-                    self.protocol_time += perf_counter() - t0
-                else:
-                    reply = srv.handle(client, msg)
+                reply = _handle(srv, state.fut, msg)
                 if rt is not None:
                     rt.after_handle(sid)
                 if dupped:
@@ -1483,7 +1505,7 @@ class Network:
                     return
                 if self.sanitizer is not None:
                     self.sanitizer.on_reply(sid, msg, reply)
-                rsize = msg_wire_size(reply)
+                rsize = _framed(reply)
                 self.msg_count += 1
                 self.bytes_sent += rsize
                 self._acct_add(state.acct, 0, 1, rsize)

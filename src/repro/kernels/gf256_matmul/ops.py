@@ -6,7 +6,9 @@ host-side prep (bit-matrix expansion of the tiny A, width padding) + the
 Pallas kernel where it compiles natively (TPU), the jit'd XLA LUT
 formulation elsewhere. ``interpret=True`` runs the kernel in the Pallas
 interpreter, a correctness harness orders of magnitude slower than either
-and never a production path.
+and never a production path. Every path takes the operand as a flat
+``(k*Lp,)`` buffer and returns the product as a flat ``(m*Lp,)`` one, so
+both cross between host and device lane-dense (see ``kernel``).
 ``rs_encode_parity(parity_matrix, data)`` — the RS encode hot path.
 
 All paths are bit-identical to ``ref.gf256_matmul_ref`` (and to the numpy
@@ -54,9 +56,13 @@ def _abits_cached(a_bytes: bytes, m: int, k: int) -> np.ndarray:
 
 @functools.lru_cache(maxsize=1)
 def _jit_ref():
+    """The jit'd LUT reference at the kernel's flat boundary."""
     from repro.kernels.gf256_matmul.ref import gf256_matmul_ref
 
-    return jax.jit(gf256_matmul_ref)
+    def flat(A, b):
+        return gf256_matmul_ref(A, b.reshape(A.shape[1], -1)).reshape(-1)
+
+    return jax.jit(flat)
 
 
 def gf256_matmul(
@@ -74,7 +80,11 @@ def gf256_matmul(
     zero-padded on the host to ``dispatch.width_bucket(L)`` and the product
     sliced back on the host — GF matmul is column-wise, so padding columns
     is bit-identical — which bounds compiles across ragged widths to
-    O(log L) per (m, k) and keeps ragged shapes off the device.
+    O(log L) per (m, k) and keeps ragged shapes off the device. The padded
+    operand goes to the device as its flat ``(k*Lp,)`` view and the product
+    comes back as a flat ``(m*Lp,)`` buffer, viewed as ``(m, Lp)`` on the
+    host: both views are free, and a flat uint8 buffer crosses faster than
+    a 2-D one with a few rows, which sits in a sparse tile (see ``kernel``).
 
     Each host step is a ``repro.tracing`` span: ``gf256.pad`` (to the
     bucket), ``gf256.put`` (the operand to the device), ``gf256.run``
@@ -84,8 +94,9 @@ def gf256_matmul(
     for it, so each step is timed alone; the path already waited in
     ``get``, so no wait moves. Off, the launch moves the operand as it
     always has. Launches are counted by operand rows (more than the code's
-    k: a block-diagonal fused decode), with the bytes sent unpadded and
-    padded and the bytes fetched.
+    k: a block-diagonal fused decode), and those whose product came back
+    flat (``gf256.launch.flat``), with the bytes sent unpadded and padded
+    and the bytes fetched.
     """
     A = np.asarray(A, dtype=np.uint8)
     B = np.asarray(B, dtype=np.uint8)
@@ -104,29 +115,31 @@ def gf256_matmul(
             Bp = np.zeros((k, Lp), dtype=np.uint8)
             Bp[:, :L] = B
             B = Bp
+        flat = B.reshape(-1)
     if traced:
         with tracing.span("gf256.put", nbytes=k * Lp):
-            B = jax.device_put(B).block_until_ready()
+            flat = jax.device_put(flat).block_until_ready()
     with tracing.span("gf256.run"):
         if interpret is None and not dispatch.kernel_is_native():
-            out = _jit_ref()(A, B)
+            out = _jit_ref()(A, flat)
         else:
             abits = _abits_cached(A.tobytes(), m, k)
-            out = gf2_bitsliced_matmul(abits, B, m=m, k=k, block_l=min(block_l, Lp),
+            out = gf2_bitsliced_matmul(abits, flat, m=m, k=k, block_l=min(block_l, Lp),
                                        interpret=bool(interpret))
         if traced:
             out.block_until_ready()
     with tracing.span("gf256.get", nbytes=m * Lp):
         host = np.asarray(out)
     with tracing.span("gf256.slice"):
-        host = host[:, :L]
+        product = host.reshape(m, Lp)[:, :L]
     if traced:
         tracing.count("gf256.launch")
         tracing.count(f"gf256.launch.rows.{k}")
+        tracing.count("gf256.launch.flat", int(host.ndim == 1))
         tracing.count("gf256.bytes_in", k * L)
         tracing.count("gf256.bytes_in_padded", k * Lp)
         tracing.count("gf256.bytes_out", m * Lp)
-    return host
+    return product
 
 
 def rs_encode_parity(

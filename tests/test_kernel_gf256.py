@@ -121,3 +121,49 @@ def test_rs_encode_parity_wrapper():
     data = rng.integers(0, 256, (8, 1024), dtype=np.uint8)
     par = np.asarray(rs_encode_parity(code.parity_matrix, data, interpret=True))
     np.testing.assert_array_equal(par, code.encode(data)[8:])
+
+
+def _block_diagonal(rng, k: int, groups: int) -> np.ndarray:
+    """A fused decode's matrix: one k x k block per survivor set."""
+    A = np.zeros((groups * k, groups * k), dtype=np.uint8)
+    for g in range(groups):
+        A[g * k:(g + 1) * k, g * k:(g + 1) * k] = rng.integers(0, 256, (k, k), dtype=np.uint8)
+    return A
+
+
+@pytest.mark.parametrize("path", ["interpret", "jit_ref"])
+@pytest.mark.parametrize("L", [1, 127, 128, 2048, 2049, 4097])
+@pytest.mark.parametrize("m,k", [(5, 6), (6, 6), (4, 2), (12, 12)],
+                         ids=["encode-emulab", "decode-emulab", "encode-aws", "fused-decode"])
+def test_flat_boundary_matches_lut(m, k, L, path, monkeypatch):
+    """The device program takes the padded operand as one flat (k*Lp,)
+    buffer and returns a flat (m*Lp,) product; the wrapper's (m, L) result
+    is bit-identical to the numpy LUT at every bucket edge."""
+    from repro.kernels import dispatch
+    from repro.kernels.gf256_matmul import ops
+
+    rng = np.random.default_rng(m * 7919 + k * 131 + L)
+    A = (_block_diagonal(rng, 6, 2) if m == 12
+         else rng.integers(0, 256, (m, k), dtype=np.uint8))
+    B = rng.integers(0, 256, (k, L), dtype=np.uint8)
+    Lp = dispatch.width_bucket(L)
+    seen = []
+
+    def spy(fn):
+        def call(*args, **kw):
+            out = fn(*args, **kw)
+            seen.append((args[1].shape, out.shape))
+            return out
+        return call
+
+    if path == "interpret":
+        monkeypatch.setattr(ops, "gf2_bitsliced_matmul", spy(ops.gf2_bitsliced_matmul))
+        got = gf256_matmul(A, B, interpret=True)
+    else:
+        monkeypatch.setattr(dispatch, "kernel_is_native", lambda: False)
+        ref = spy(ops._jit_ref())
+        monkeypatch.setattr(ops, "_jit_ref", lambda: ref)
+        got = gf256_matmul(A, B)
+    assert seen == [((k * Lp,), (m * Lp,))]
+    assert got.shape == (m, L) and got.dtype == np.uint8
+    np.testing.assert_array_equal(got, gf_matmul_np(A, B))

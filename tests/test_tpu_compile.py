@@ -8,9 +8,11 @@ every test skips where it cannot be described. The shapes are the main
 path's: the Emulab store's encode (5, 6), decode (6, 6), two-set fused
 decode (12, 12) and largest fused decode (128, 128), the AWS store's encode
 (4, 2), and the CDC kernel over a 1 MiB block and the largest object bucket
-(512 MiB).
+(512 MiB). Both kernels' programs take and return flat buffers, as the
+wrappers move them.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,10 +81,15 @@ def _fits_hbm(compiled) -> bool:
 def test_gf256_kernel_compiles_for_v5e(m, k, L, one_chip, no_persistent_cache):
     abits = jax.ShapeDtypeStruct(
         (_round_up(8 * m, 8), _round_up(8 * k, 128)), jnp.float32, sharding=one_chip)
-    b = jax.ShapeDtypeStruct((k, L), jnp.uint8, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((k * L,), jnp.uint8, sharding=one_chip)  # flat (k, L)
     compiled = _compile(
         lambda a, x: gf2_bitsliced_matmul(a, x, m=m, k=k, block_l=2048), abits, b)
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert compiled.out_info.shape == (m * L,)
+    # the flat buffers are bitcast to the kernel's views: no byte relayout
+    u8_ops = set(re.findall(r"= u8\[[^\]]*\]\S* ([\w-]+)\(", text))
+    assert u8_ops == {"parameter", "bitcast", "custom-call"}
     assert _fits_hbm(compiled)
 
 

@@ -109,6 +109,7 @@ def test_the_launch_counter_agrees_with_the_benchmark_meter(monkeypatch):
     assert meter.calls[GF] > 0
     assert counts["gf256.launch"] == meter.calls[GF]
     assert counts["gf256.launch.rows.6"] == meter.calls[GF]
+    assert counts["gf256.launch.flat"] == counts["gf256.launch"]
     assert counts["gf256.bytes_in_padded"] >= counts["gf256.bytes_in"] > 0
 
 
@@ -141,8 +142,11 @@ def test_a_new_width_compiles_under_the_run_span():
     rec = tracing.recording()
     assert rec["compiles"].get("gf256.run", 0) >= 1
     assert not set(rec["compiles"]) - {"gf256.run"}
-    assert rec["counts"]["gf256.launch.rows.1"] == 1
+    # one flat launch: k * L bytes sent, k * Lp with padding, m * Lp back
+    assert rec["counts"]["gf256.launch.rows.1"] == rec["counts"]["gf256.launch.flat"] == 1
+    assert rec["counts"]["gf256.bytes_in"] == 3 * 2**13 + 1
     assert rec["counts"]["gf256.bytes_in_padded"] == 2**15
+    assert rec["counts"]["gf256.bytes_out"] == 7 * 2**15
     # the same width again compiles nothing
     gf256_matmul(A, np.ones((1, 2**15), np.uint8))
     assert tracing.recording()["compiles"] == rec["compiles"]

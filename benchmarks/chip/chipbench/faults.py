@@ -16,6 +16,10 @@ tests switch one on around ``run_cell``.
 ``unstored``
     a step that returns its state unchanged: servers acknowledge the coded
     fragments of writes without storing them.
+``stale``
+    an answer altered where it is produced, in the block diff: an update
+    leaves one changed block of the file's old chain unwritten (the plan
+    keeps its old data), and the op still acknowledges.
 """
 from __future__ import annotations
 
@@ -23,7 +27,7 @@ from contextlib import contextmanager
 
 import numpy as np
 
-NAMES = ("int8", "flip", "half", "unstored")
+NAMES = ("int8", "flip", "half", "unstored", "stale")
 
 
 def _int8(A, B, **_kw):
@@ -44,8 +48,22 @@ def _altered(fn, how: str):
     return call
 
 
+def _stale(plan):
+    def call(self, fid, old_blocks, content):
+        final, chunks = plan(self, fid, old_blocks, content)
+        old = {bid: data for bid, _nxt, data in old_blocks}
+        for i, (bid, data) in enumerate(final):
+            if old.get(bid, data) != data:
+                final[i] = (bid, old[bid])
+                break
+        return final, chunks
+
+    return call
+
+
 @contextmanager
 def planted(name: str):
+    from repro.core.fragment import FragmentationModule
     from repro.core.server import StorageServer
     from repro.kernels.gf256_matmul import ops as gf_ops
 
@@ -61,6 +79,8 @@ def planted(name: str):
         patch(gf_ops, "gf256_matmul", _int8)
     elif name in ("flip", "half"):
         patch(gf_ops, "gf256_matmul", _altered(gf_ops.gf256_matmul, name))
+    elif name == "stale":
+        patch(FragmentationModule, "_plan_blocks", _stale(FragmentationModule._plan_blocks))
     else:
         patch(StorageServer, "_h_ec_put", lambda self, sender, msg: ("ack",))
     try:

@@ -29,14 +29,19 @@ def emit(row: dict) -> None:
     print(json.dumps(row), flush=True)
 
 
-def build_store(config: dict, seed: int):
+# the simulated network's delays: one stream for every run, so that every
+# seed replays the same schedule of ops (see traffic.py)
+NETWORK_SEED = 0
+
+
+def build_store(config: dict):
     from repro.core import DSS, DSSParams
     from repro.net.sim import LatencyModel
 
     lat = config["latency"]
     return DSS(DSSParams(
         algorithm=config["algorithm"], n_servers=config["n_servers"],
-        parity_m=config["parity_m"], seed=seed % (1 << 32),
+        parity_m=config["parity_m"], seed=NETWORK_SEED,
         min_block=config["min_block"], avg_block=config["avg_block"],
         max_block=config["max_block"], indexed=config["indexed"],
         coding_backend=config["coding_backend"],
@@ -46,9 +51,43 @@ def build_store(config: dict, seed: int):
 
 
 def _split(specs: list, slots: int) -> list:
-    """Set-up writes spread over the writer slots, one op each at a time."""
-    return [iter([replace(s, session=f"writer{i}") for s in specs[i::slots]])
+    """Set-up writes spread over the writer slots, one op each at a time; a
+    write that names its session keeps it."""
+    return [iter([replace(s, session=s.session or f"writer{i}") for s in specs[i::slots]])
             for i in range(slots) if specs[i::slots]]
+
+
+def _warm_coding(config: dict, largest: int, warm: dict) -> None:
+    """Compile the GF(256) kernel at every shape the window's coding can
+    take that the warm-up ops need not reach: the encode of 1 to
+    ``encode_blocks`` changed blocks, and the decode of anything up to the
+    largest object from 1 to ``decode_groups`` fragment index sets (a
+    decode whose blocks heard from different servers fuses them
+    block-diagonally). Widths run from the program's kernel threshold up,
+    bucket by bucket, as the program pads them."""
+    import numpy as np
+
+    from repro.erasure import rs
+    from repro.kernels.dispatch import width_bucket
+    from repro.kernels.gf256_matmul import ops as gf_ops
+
+    n, m = config["n_servers"], config["parity_m"]
+    k = n - m
+    blocks = -(-largest // config["min_block"])
+
+    def run(rows: int, cols: int, value_bytes: int, values: int) -> None:
+        # a value is its 2-byte header and a chunk, padded to whole rows
+        w = width_bucket(-(-rs.AUTO_KERNEL_MIN_BYTES // cols))
+        hi = width_bucket(-(-(value_bytes + 2 * values) // k) + values)
+        while w <= hi:
+            gf_ops.gf256_matmul(np.ones((rows, cols), np.uint8), np.zeros((cols, w), np.uint8))
+            w *= 2
+
+    changed = int(warm.get("encode_blocks", 0))
+    if changed:
+        run(m, k, changed * config["max_block"], changed)
+    for g in range(1, int(warm.get("decode_groups", 0)) + 1):
+        run(g * k, g * k, largest, blocks)
 
 
 def _failures(win, what: str) -> int:
@@ -81,7 +120,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     """Everything of a run after the device check; returns the result line
     without ``device``."""
     config, traffic = cell.config, Traffic(cell.traffic, cell.config, seed)
-    dss = build_store(config, seed)
+    dss = build_store(config)
     with Meter(code_k=dss.c0.k, annotate=traced) as meter:
         closed = loop.ClosedLoop(dss, traffic, annotate=traced)
         # -- set-up: preload, outage, warm-up of this cell's own shapes -------
@@ -91,6 +130,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         down = [dss.c0.servers[i] for i in traffic.fragments("down_fragments")]
         dss.crash_servers(down)
         failed += _failures(closed.run([iter(traffic.warmup())], None), "warm-up")
+        if "warm_coding" in traffic.params:
+            _warm_coding(config, max(traffic.sizes), traffic.params["warm_coding"])
         setup_s = time.perf_counter() - t0
         emit({"row": "setup", "setup_s": setup_s, "preload_objects": len(preload),
               "preload_bytes": sum(s.size for s in preload), "down": down, **meter.snapshot()})
@@ -116,7 +157,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
                                 protocol_s=dss.net.protocol_time)
 
             streams = [traffic.stream(kind, slot)
-                       for kind in ("write", "read") for slot in range(traffic.slots(kind))]
+                       for kind in ("write", "edit", "read")
+                       for slot in range(traffic.slots(kind))]
             win = closed.run(streams, seconds, on_close=on_close)
             if traced:
                 jax.profiler.stop_trace()

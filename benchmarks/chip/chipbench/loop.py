@@ -30,6 +30,7 @@ class Done:
     error: str | None = None
     answer: bytes | None = None  # a kept read's bytes
     index: tuple = ()
+    result: dict | None = None   # what the program returned for a write
 
 
 @dataclass
@@ -92,7 +93,9 @@ class ClosedLoop:
             rec.nbytes = len(answer)
             if spec.keep:
                 rec.answer = answer
-        elif answer.get("success"):
+            return rec
+        rec.result = answer
+        if answer.get("success"):
             rec.nbytes = spec.size
         else:
             rec.error = f"write not applied: {answer}"

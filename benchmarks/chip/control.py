@@ -3,7 +3,7 @@
 compares when the timed path is wrong. Not part of a benchmark run.
 
     python3 benchmarks/chip/control.py --workload <cell> --seed <n> \\
-        --seconds <s> --fault int8|flip|half|unstored [--seed <n> ...]
+        --seconds <s> --fault int8|flip|half|unstored|stale [--seed <n> ...]
 
 Several ``--seed`` values run one after another in this one process, so the
 chip is set up once. Each prints the result line; ``correct`` should read

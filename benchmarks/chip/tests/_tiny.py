@@ -17,7 +17,8 @@ PEAKS = {"hbm_bytes_per_s": 819e9}
 def tiny_cell(name: str) -> spec.Cell:
     cell = spec.load_cell(name)
     cell.config.update(min_block=2048, avg_block=2048, max_block=4096)
-    cell.traffic["object_sizes_mib"] = [s / 256 for s in cell.traffic["object_sizes_mib"]]
+    key = "file_sizes_mib" if "file_sizes_mib" in cell.traffic else "object_sizes_mib"
+    cell.traffic[key] = [s / 256 for s in cell.traffic[key]]
     if cell.traffic.get("preload_per_size"):
         cell.traffic["preload_per_size"] = 2
     return cell

@@ -1,8 +1,9 @@
 """With the control or a fault planted under the timed path, the check
 comes out false: the control (8-bit integer arithmetic in the GF(256)
 kernel's place), an answer altered where it is produced, half of the batch
-left out, and a step that leaves the store's state unchanged. One cell has
-no exchange between chips, so that fault does not apply."""
+left out, a step that leaves the store's state unchanged, and (where files
+are edited) a block diff that leaves a changed block unwritten. No cell
+has an exchange between chips, so that fault does not apply."""
 import pytest
 
 from _tiny import run
@@ -13,6 +14,8 @@ CASES = [
     ("aws_k2.ingest", "int8"),
     ("emulab_k6.degraded_read", "int8"), ("emulab_k6.degraded_read", "flip"),
     ("emulab_k6.degraded_read", "half"),
+    ("emulab_k6.edit", "int8"), ("emulab_k6.edit", "flip"), ("emulab_k6.edit", "half"),
+    ("emulab_k6.edit", "unstored"), ("emulab_k6.edit", "stale"),
 ]
 
 

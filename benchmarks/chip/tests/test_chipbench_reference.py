@@ -43,3 +43,61 @@ def test_block_values_and_genesis_parse_follow_the_program_layout():
     index = ["f\x01c\x011", "f\x01c\x012"]
     assert reference.parse_genesis(encode_block_value(index[0], encode_genesis_meta(index))) == index
     assert reference.genesis_id("f") == genesis_id("f")
+
+
+def _edited_store(history: list[bytes]):
+    """A tiny store of the edit cell's deployment after ``history``, each
+    content saved in turn by one session, as an edit saves it."""
+    from _tiny import tiny_cell
+    from chipbench import harness
+
+    config = tiny_cell("emulab_k6.edit").config
+    dss = harness.build_store(config)
+    session = dss.session("writer0")
+    for content in history:
+        assert session.write("f", content).result()["success"]
+    dss.net.run()
+    return dss, config
+
+
+def _without_third_chunk(data: bytes) -> bytes:
+    a, b = np.cumsum(reference.chunk_lengths(data, 2048, 2048, 4096))[1:3]
+    return data[:a] + data[b:]
+
+
+def _histories() -> dict:
+    """Edit histories that all end at one content: seeded one-byte flips
+    only, or with a chunk deleted first or last (which leaves a
+    tombstone in the block index)."""
+    from _tiny import tiny_cell
+    from chipbench.traffic import Traffic
+
+    cell = tiny_cell("emulab_k6.edit")
+    t = Traffic(cell.traffic, cell.config, 2**33 + 9)
+    flips = [t.version(0, 2, v) for v in range(6)]
+    final = _without_third_chunk(flips[-1])
+    return {
+        "flips": (flips, flips[-1], 0),
+        "delete_then_flip": ([flips[0], _without_third_chunk(flips[0]), final], final, 1),
+        "flip_then_delete": ([*flips, final], final, 1),
+        "at_once": ([flips[0], final], final, 1),
+    }
+
+
+@pytest.mark.parametrize("history", ["flips", "delete_then_flip", "flip_then_delete", "at_once"])
+def test_live_blocks_after_edits_equal_a_fresh_chunking(history):
+    """Chunking depends on content alone, so whatever the edits, the
+    blocks left live hold the reference's blocks of the final content;
+    tombstones are skipped by the check and stay in the index."""
+    from chipbench import check
+
+    steps, final, tombstones = _histories()[history]
+    dss, config = _edited_store(steps)
+    raw = check._stored_value(dss, reference.genesis_id("f"))
+    values = [check._stored_value(dss, bid) for bid in reference.parse_genesis(raw)]
+    live = [v for v in values if v != check.TOMBSTONE]
+    assert live == reference.block_values(final, 2048, 2048, 4096)
+    assert len(values) - len(live) == tombstones
+    assert check.stored_blocks_wrong(dss, config, {"f": final}) == 0
+    # the same comparison against the content before the last edit fails
+    assert check.stored_blocks_wrong(dss, config, {"f": steps[-2]}) > 0

@@ -11,7 +11,7 @@ from chipbench import spans  # noqa: E402
 from chipbench import trace as tr  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
-CELLS = ["emulab_k6.ingest", "aws_k2.ingest", "emulab_k6.degraded_read"]
+CELLS = ["emulab_k6.ingest", "aws_k2.ingest", "emulab_k6.degraded_read", "emulab_k6.edit"]
 NEW = {"pad_s_per_GiB", "transfer_s_per_GiB", "padding_share_pct", "copy_s_per_GiB",
        "crc_s_per_GiB", "chunking_host_s_per_GiB", "framing_s_per_GiB", "server_s_per_GiB"}
 

@@ -140,7 +140,8 @@ def warm_up(dss, payloads: dict, *, min_block: int, avg_block: int, n_down: int)
     object's length bucket, and the GF(256) kernel at each width bucket a
     kernel-sized operand can take, up to the whole load in one batch, for
     encode (m, k), decode (k, k), a two-set fused decode (2k, 2k) and
-    repair (n_down, k)."""
+    repair (n_down, k). Above a slice size (``kernels.dispatch``) these
+    calls launch, and so compile, the slices the steady phases launch."""
     import numpy as np
 
     from repro.erasure.rs import AUTO_KERNEL_MIN_BYTES

@@ -14,11 +14,12 @@ of the 128-lane vreg width, and the grid walks blocks of ``block_rows``
 rows (a multiple of the 32-row uint8 sublane tile, or all rows). Position
 ``c`` of a row needs bytes ``c-31..c``; for ``c < 31`` some of them sit at
 the end of the PREVIOUS row. Blocks cannot overlap, so the wrapper passes
-each row's predecessor tail — the previous row's last 128 bytes, zeros for
-row 0 — as a second ``(R, 128)`` operand. In-kernel, ``pltpu.roll`` along
-the lanes shifts the gear stream by j: correct everywhere except the first
-31 lanes, which the first lane-tile recomputes with the tail spliced in
-(``lane < j`` selects the rolled tail). Every block is tile-aligned, no
+each row's predecessor tail — the previous row's last 128 bytes; for row 0
+the 128 bytes before the stream (``head``, when the stream is one slice of
+a longer one), else zeros — as a second ``(R, 128)`` operand. In-kernel,
+``pltpu.roll`` along the lanes shifts the gear stream by j: correct
+everywhere except the first 31 lanes, which the first lane-tile recomputes
+with the tail spliced in (``lane < j`` selects the rolled tail). Every block is tile-aligned, no
 slice is unaligned, and the output is the uint32 hash stream and a uint8
 boundary bitmap (h & mask == 0).
 
@@ -94,19 +95,21 @@ def layout(L: int, block_l: int) -> tuple[int, int]:
 
 @functools.partial(jax.jit, static_argnames=("block_l", "mask", "interpret"))
 def gearhash_pallas(
-    data: jax.Array, *, block_l: int = 1 << 17, mask: int = 0xFFFF,
-    interpret: bool = False,
+    data: jax.Array, head: jax.Array | None = None, *, block_l: int = 1 << 17,
+    mask: int = 0xFFFF, interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """data: (L,) uint8 with a tile-aligned view (see ``layout``). Returns
-    (hash (L,) uint32, boundary bitmap (L,) uint8). Positions < W-1 hash a
-    window zero-padded in byte space (row 0's tail is all zeros)."""
+    (hash (L,) uint32, boundary bitmap (L,) uint8). ``head``: the (128,)
+    bytes just before ``data`` in a longer stream, row 0's tail, so the
+    result equals that stream's own at these positions; without it,
+    positions < W-1 hash a window zero-padded in byte space."""
     L = data.shape[0]
     width, block_rows = layout(L, block_l)
     rows = L // width
     d2 = data.reshape(rows, width)
-    tails = jnp.concatenate(
-        [jnp.zeros((1, LANES), jnp.uint8), d2[:-1, width - LANES:]], axis=0
-    )
+    first = (jnp.zeros((1, LANES), jnp.uint8) if head is None
+             else head.astype(jnp.uint8).reshape(1, LANES))
+    tails = jnp.concatenate([first, d2[:-1, width - LANES:]], axis=0)
     h, b = pl.pallas_call(
         functools.partial(_gearhash_kernel, mask=mask),
         grid=(rows // block_rows,),

@@ -6,13 +6,26 @@ hardware; elsewhere the wrappers run their jit'd pure-jnp references
 ``width_bucket(L)`` is the one padding policy: every wrapper zero-pads its
 operand on the host to this width and slices the result back on the host,
 so a stream of mixed sizes compiles O(log L) programs, not one per size.
-Callers look both up through this module, so a test can patch them once.
+Above a slice size a call launches in slices (``slices``): every slice but
+the last is exactly that size, and the last is bucketed as a whole call
+would be, so no program is wider than a slice. That bounds a program's
+width, not a call's device memory: a GF(256) call launches every slice
+before it fetches any. Callers look all of these up through this module, so a test
+can patch them once.
 """
 from __future__ import annotations
 
 import jax
 
 _LANE = 128
+
+# Slice sizes, powers of two: a CDC stream longer than CDC_SLICE_BYTES is
+# hashed CDC_SLICE_BYTES at a time, and a GF(256) operand wider than
+# GF_SLICE_COLS columns is multiplied GF_SLICE_COLS columns at a time. Both
+# lie above every width the 1-64 MiB objects reach (a 64 MiB stream, a
+# 2^24-column decode of one), so those launch whole.
+CDC_SLICE_BYTES = 1 << 26
+GF_SLICE_COLS = 1 << 24
 
 
 def kernel_is_native() -> bool:
@@ -26,3 +39,9 @@ def width_bucket(L: int) -> int:
     """The padded width an L-column operand or L-byte stream is computed
     at: the next power of two, at least one lane row."""
     return max(_LANE, 1 << (L - 1).bit_length())
+
+
+def slices(L: int, size: int) -> list[tuple[int, int]]:
+    """The ``[lo, hi)`` bounds an L-long operand is launched in: one
+    ``(0, L)`` up to ``size``, else slices of ``size`` and a ragged last."""
+    return [(lo, min(lo + size, L)) for lo in range(0, L, size)] if L > size else [(0, L)]

@@ -65,6 +65,50 @@ def _jit_ref():
     return jax.jit(flat)
 
 
+def _launch(
+    A: np.ndarray, B: np.ndarray, block_l: int, interpret: bool | None
+) -> tuple[jax.Array, int]:
+    """One launch on the columns ``B`` holds: the flat product still on the
+    device, and the width it was computed at. Each host step is a span."""
+    m, k = A.shape
+    L = B.shape[1]
+    Lp = dispatch.width_bucket(L)
+    traced = tracing.enabled()
+    with tracing.span("gf256.pad", nbytes=k * Lp):
+        if Lp != L:
+            Bp = np.zeros((k, Lp), dtype=np.uint8)
+            Bp[:, :L] = B
+            B = Bp
+        flat = B.reshape(-1)  # a copy where B is a column slice
+    if traced:
+        with tracing.span("gf256.put", nbytes=k * Lp):
+            flat = jax.device_put(flat).block_until_ready()
+    with tracing.span("gf256.run"):
+        if interpret is None and not dispatch.kernel_is_native():
+            out = _jit_ref()(A, flat)
+        else:
+            abits = _abits_cached(A.tobytes(), m, k)
+            out = gf2_bitsliced_matmul(abits, flat, m=m, k=k, block_l=min(block_l, Lp),
+                                       interpret=bool(interpret))
+        if traced:
+            out.block_until_ready()
+    if traced:
+        tracing.count("gf256.launch")
+        tracing.count(f"gf256.launch.rows.{k}")
+        tracing.count("gf256.launch.flat", int(out.ndim == 1))
+        tracing.count("gf256.bytes_in", k * L)
+        tracing.count("gf256.bytes_in_padded", k * Lp)
+        tracing.count("gf256.bytes_out", m * Lp)
+    return out, Lp
+
+
+def _get(out: jax.Array, Lp: int, m: int) -> np.ndarray:
+    """A flat product back on the host, viewed as ``(m, Lp)``."""
+    with tracing.span("gf256.get", nbytes=m * Lp):
+        host = np.asarray(out)
+    return host.reshape(m, Lp)
+
+
 def gf256_matmul(
     A: np.ndarray,
     B: np.ndarray | jax.Array,
@@ -85,18 +129,24 @@ def gf256_matmul(
     comes back as a flat ``(m*Lp,)`` buffer, viewed as ``(m, Lp)`` on the
     host: both views are free, and a flat uint8 buffer crosses faster than
     a 2-D one with a few rows, which sits in a sparse tile (see ``kernel``).
+    An operand wider than ``dispatch.GF_SLICE_COLS`` columns is cut into
+    column slices of that width, the last bucketed as above; every slice is
+    launched, then each product is fetched in order into one ``(m, L)``
+    result, bit-identical for the same reason.
 
-    Each host step is a ``repro.tracing`` span: ``gf256.pad`` (to the
-    bucket), ``gf256.put`` (the operand to the device), ``gf256.run``
-    (launch to ready), ``gf256.get`` (the product back) and ``gf256.slice``
-    (back to L). Only while the tracer is on is the operand put on the
-    device before the launch, and do ``put`` and ``run`` end by waiting
-    for it, so each step is timed alone; the path already waited in
-    ``get``, so no wait moves. Off, the launch moves the operand as it
-    always has. Launches are counted by operand rows (more than the code's
-    k: a block-diagonal fused decode), and those whose product came back
-    flat (``gf256.launch.flat``), with the bytes sent unpadded and padded
-    and the bytes fetched.
+    Each host step of a launch is a ``repro.tracing`` span: ``gf256.pad``
+    (to the bucket, or a column slice made contiguous), ``gf256.put`` (the
+    operand to the device), ``gf256.run`` (launch to ready), ``gf256.get``
+    (the product back) and ``gf256.slice`` (back to L). Only while the
+    tracer is on is the operand put on the device before the launch, and do
+    ``put`` and ``run`` end by waiting for it, so each step is timed alone;
+    the path already waited in ``get``, so no wait moves. Off, the launch
+    moves the operand as it always has. Launches are counted by operand
+    rows (more than the code's k: a block-diagonal fused decode), and those
+    whose product came back flat (``gf256.launch.flat``), with the bytes
+    sent unpadded and padded and the bytes fetched; those that are one
+    slice of a wider operand are counted too (``gf256.slices``), with their
+    unpadded bytes (``gf256.bytes_in_sliced``).
     """
     A = np.asarray(A, dtype=np.uint8)
     B = np.asarray(B, dtype=np.uint8)
@@ -108,37 +158,21 @@ def gf256_matmul(
         # empty values): the product is an empty/zero matrix — don't hand
         # a zero-sized grid to Pallas.
         return np.zeros((m, L), dtype=np.uint8)
-    Lp = dispatch.width_bucket(L)
-    traced = tracing.enabled()
-    with tracing.span("gf256.pad", nbytes=k * Lp):
-        if Lp != L:
-            Bp = np.zeros((k, Lp), dtype=np.uint8)
-            Bp[:, :L] = B
-            B = Bp
-        flat = B.reshape(-1)
-    if traced:
-        with tracing.span("gf256.put", nbytes=k * Lp):
-            flat = jax.device_put(flat).block_until_ready()
-    with tracing.span("gf256.run"):
-        if interpret is None and not dispatch.kernel_is_native():
-            out = _jit_ref()(A, flat)
-        else:
-            abits = _abits_cached(A.tobytes(), m, k)
-            out = gf2_bitsliced_matmul(abits, flat, m=m, k=k, block_l=min(block_l, Lp),
-                                       interpret=bool(interpret))
-        if traced:
-            out.block_until_ready()
-    with tracing.span("gf256.get", nbytes=m * Lp):
-        host = np.asarray(out)
-    with tracing.span("gf256.slice"):
-        product = host.reshape(m, Lp)[:, :L]
-    if traced:
-        tracing.count("gf256.launch")
-        tracing.count(f"gf256.launch.rows.{k}")
-        tracing.count("gf256.launch.flat", int(host.ndim == 1))
-        tracing.count("gf256.bytes_in", k * L)
-        tracing.count("gf256.bytes_in_padded", k * Lp)
-        tracing.count("gf256.bytes_out", m * Lp)
+    parts = dispatch.slices(L, dispatch.GF_SLICE_COLS)
+    if len(parts) == 1:
+        host = _get(*_launch(A, B, block_l, interpret), m)
+        with tracing.span("gf256.slice"):
+            return host[:, :L]
+    launched = []
+    for lo, hi in parts:
+        launched.append((lo, hi, *_launch(A, B[:, lo:hi], block_l, interpret)))
+        tracing.count("gf256.slices")
+        tracing.count("gf256.bytes_in_sliced", k * (hi - lo))
+    product = np.empty((m, L), dtype=np.uint8)
+    for lo, hi, out, Lp in launched:
+        host = _get(out, Lp, m)
+        with tracing.span("gf256.slice"):
+            product[:, lo:hi] = host[:, :hi - lo]
     return product
 
 

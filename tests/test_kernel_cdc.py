@@ -80,3 +80,124 @@ def test_empty_and_tiny_inputs():
     assert split_chunks(b"", min_size=4, avg_size=8, max_size=16, interpret=True) == [b""]
     out = split_chunks(b"abc", min_size=4, avg_size=8, max_size=16, interpret=True)
     assert b"".join(out) == b"abc"
+
+
+# -- streams longer than a slice ----------------------------------------------
+SLICE = 4096  # dispatch.CDC_SLICE_BYTES patched down: 2^26 on the chip
+
+
+def _spied(monkeypatch, path):
+    """Route the wrapper through ``path`` and record its launches and
+    fetches in order."""
+    from repro.kernels import dispatch
+    from repro.kernels.cdc_gearhash import ops
+
+    monkeypatch.setattr(dispatch, "CDC_SLICE_BYTES", SLICE)
+    if path == "interpret":
+        monkeypatch.setattr(dispatch, "kernel_is_native", lambda: True)
+        pallas = ops.gearhash_pallas
+        monkeypatch.setattr(ops, "gearhash_pallas",
+                            lambda *a, **kw: pallas(*a, **{**kw, "interpret": True}))
+    events = []
+    launch, fetch = ops._gearhash_device, ops._fetch
+
+    def spy_launch(buf, *a, **kw):
+        events.append(("launch", buf.shape[0]))
+        return launch(buf, *a, **kw)
+
+    def spy_fetch(x, L, out=None):
+        events.append(("fetch", L))
+        return fetch(x, L, out)
+
+    monkeypatch.setattr(ops, "_gearhash_device", spy_launch)
+    monkeypatch.setattr(ops, "_fetch", spy_fetch)
+    return events
+
+
+def _gear(x: np.ndarray) -> np.ndarray:
+    """The kernel's byte mixer in numpy (uint32 arithmetic wraps)."""
+    v = (x.astype(np.uint32) + np.uint32(0x9E3779B9)) * np.uint32(0x85EBCA6B)
+    v ^= v >> np.uint32(15)
+    v *= np.uint32(0xC2B2AE35)
+    return v ^ (v >> np.uint32(13))
+
+
+def _edge_stream(L: int, bits: int) -> np.ndarray:
+    """Random bytes with boundary candidates (mask 2^bits - 1) planted at
+    the last byte of every slice and the first ``bits`` bytes after it, the
+    positions whose low hash bits read the bytes before the slice. Each is
+    planted by choosing its own byte, in order, so later bytes leave the
+    earlier candidates as they are."""
+    data = np.random.default_rng(L + bits).integers(0, 256, L, dtype=np.uint8)
+    mask = np.uint32((1 << bits) - 1)
+    every = _gear(np.arange(256, dtype=np.uint8))
+    for lo in range(SLICE, L, SLICE):
+        for p in range(lo - 1, min(lo + bits, L)):
+            window = _gear(data[p - 31:p][::-1])  # x[p-1], x[p-2], ...
+            rest = (window << np.arange(1, 32, dtype=np.uint32)).sum(dtype=np.uint32)
+            data[p] = np.nonzero(((every + rest) & mask) == 0)[0][0]
+    return data
+
+
+@pytest.mark.parametrize("path", ["interpret", "jit_ref"])
+@pytest.mark.parametrize("L", [SLICE, SLICE + 1, 3 * SLICE, 3 * SLICE + 1000, 4 * SLICE - 1])
+@pytest.mark.parametrize("bits", [4, 6])
+def test_sliced_bitmap_equals_the_whole_stream_ref(L, bits, path, monkeypatch):
+    """A stream longer than a slice is hashed slice by slice, each with the
+    bytes before it as its first row's tail: the bitmap equals the whole
+    stream's, with candidates at slice edges and within 31 bytes after
+    them, and for a ragged last slice. Slice j + 1 is launched before
+    slice j is fetched."""
+    from repro import tracing
+
+    events = _spied(monkeypatch, path)
+    data = _edge_stream(L, bits)
+    want = np.asarray(gearhash_ref(data, mask=(1 << bits) - 1)[1])
+    for lo in range(SLICE, L, SLICE):
+        assert want[lo - 1:lo + bits].all()
+    tracing.enable(True)
+    try:
+        got = boundary_bitmap(data.tobytes(), avg_size=1 << bits)
+        counts = tracing.recording()["counts"]
+    finally:
+        tracing.enable(False)
+    np.testing.assert_array_equal(got, want)
+    for lo in range(SLICE, L, SLICE):  # every slice's first 31 positions
+        np.testing.assert_array_equal(got[lo:lo + 31], want[lo:lo + 31])
+    n = -(-L // SLICE)
+    if n == 1:
+        assert events == [("launch", L), ("fetch", L)]
+        assert "cdc_kernel.slices" not in counts
+        return
+    sizes = [min(SLICE, L - lo) for lo in range(0, L, SLICE)]
+    order = [("launch", sizes[0])]
+    for j in range(1, n):
+        order += [("launch", sizes[j]), ("fetch", sizes[j - 1])]
+    assert events == order + [("fetch", sizes[-1])]
+    assert counts["cdc_kernel.slices"] == n
+    assert counts["cdc_kernel.bytes_in_sliced"] == counts["cdc_kernel.bytes_in"] == L
+
+
+@pytest.mark.parametrize("path", ["interpret", "jit_ref"])
+@pytest.mark.parametrize("lo", [128, 1000, SLICE])
+def test_a_slice_with_its_head_hashes_as_the_whole_stream(lo, path, monkeypatch):
+    """The full 32-bit hash of one slice, given the 128 bytes before it,
+    equals the whole stream's at the same positions."""
+    from repro.kernels.cdc_gearhash import ops
+
+    _spied(monkeypatch, path)
+    data = np.random.default_rng(lo).integers(0, 256, lo + 3000, dtype=np.uint8)
+    want = np.asarray(gearhash_ref(data, mask=0xFFF)[0])[lo:]
+    h, _b, L = ops._gearhash_device(data[lo:], 0xFFF, 1024, None, data[lo - 128:lo])
+    np.testing.assert_array_equal(np.asarray(h)[:L], want)
+
+
+def test_sliced_chunks_equal_whole_stream_chunks(monkeypatch):
+    from repro.kernels import dispatch
+
+    data = np.random.default_rng(8).integers(0, 256, 9 * SLICE + 77, dtype=np.uint8).tobytes()
+    kw = dict(min_size=512, avg_size=1024, max_size=2048)
+    whole = split_chunks(data, **kw)
+    monkeypatch.setattr(dispatch, "CDC_SLICE_BYTES", SLICE)
+    assert split_chunks(data, **kw) == whole
+    assert len(whole) > 20

@@ -167,3 +167,86 @@ def test_flat_boundary_matches_lut(m, k, L, path, monkeypatch):
     assert seen == [((k * Lp,), (m * Lp,))]
     assert got.shape == (m, L) and got.dtype == np.uint8
     np.testing.assert_array_equal(got, gf_matmul_np(A, B))
+
+
+# -- operands wider than a slice ---------------------------------------------
+SLICE = 2048  # dispatch.GF_SLICE_COLS patched down: 2^24 on the chip
+
+
+@pytest.mark.parametrize("path", ["interpret", "jit_ref"])
+@pytest.mark.parametrize("L", [SLICE - 1, SLICE, SLICE + 1, 3 * SLICE + 5])
+@pytest.mark.parametrize("m,k", [(5, 6), (6, 6), (12, 12)],
+                         ids=["encode-emulab", "decode-emulab", "fused-decode"])
+def test_sliced_product_matches_lut(m, k, L, path, monkeypatch):
+    """An operand wider than a slice is multiplied slice by slice, the last
+    bucketed, every slice launched before any product is fetched; the
+    (m, L) result is bit-identical to the numpy LUT, for a block-diagonal
+    fused decode too."""
+    from repro import tracing
+    from repro.kernels import dispatch
+    from repro.kernels.gf256_matmul import ops
+
+    monkeypatch.setattr(dispatch, "GF_SLICE_COLS", SLICE)
+    rng = np.random.default_rng(m * 7919 + k * 131 + L)
+    A = (_block_diagonal(rng, 6, 2) if m == 12
+         else rng.integers(0, 256, (m, k), dtype=np.uint8))
+    B = rng.integers(0, 256, (k, L), dtype=np.uint8)
+    events = []
+    launch, get = ops._launch, ops._get
+
+    def spy_launch(A, B, *a):
+        events.append(("launch", B.shape[1]))
+        return launch(A, B, *a)
+
+    def spy_get(out, Lp, m):
+        events.append(("get", Lp))
+        return get(out, Lp, m)
+
+    monkeypatch.setattr(ops, "_launch", spy_launch)
+    monkeypatch.setattr(ops, "_get", spy_get)
+    if path == "jit_ref":
+        monkeypatch.setattr(dispatch, "kernel_is_native", lambda: False)
+    tracing.enable(True)
+    try:
+        got = gf256_matmul(A, B, interpret=True if path == "interpret" else None)
+        counts = tracing.recording()["counts"]
+    finally:
+        tracing.enable(False)
+    assert got.shape == (m, L) and got.dtype == np.uint8
+    np.testing.assert_array_equal(got, gf_matmul_np(A, B))
+    widths = [min(SLICE, L - lo) for lo in range(0, L, SLICE)]
+    buckets = [dispatch.width_bucket(w) for w in widths]
+    assert events == [("launch", w) for w in widths] + [("get", b) for b in buckets]
+    assert counts["gf256.launch"] == len(widths)
+    assert counts["gf256.bytes_in_padded"] == k * sum(buckets)
+    if len(widths) == 1:
+        assert "gf256.slices" not in counts
+    else:
+        assert counts["gf256.slices"] == len(widths)
+        assert counts["gf256.bytes_in_sliced"] == counts["gf256.bytes_in"] == k * L
+
+
+def test_sliced_fused_decode_through_the_code(monkeypatch):
+    """``decode_bytes_batch`` over two survivor sets, fused block-diagonally
+    into one product wider than a slice, returns every value."""
+    from repro.kernels import dispatch
+
+    monkeypatch.setattr(dispatch, "GF_SLICE_COLS", SLICE)
+    code = RSCode(n=11, k=6, backend="kernel", fuse_groups=True)
+    rng = np.random.default_rng(21)
+    values = [rng.bytes(n) for n in (6 * SLICE + 9, 3 * SLICE, 40_000)]
+    items = []
+    for j, (frags, orig, crcs) in enumerate(code.encode_bytes_batch(values, with_crc=True)):
+        lost = {0} if j % 2 else {0, 1}  # two survivor sets
+        keep = {i: f for i, f in enumerate(frags) if i not in lost}
+        items.append((keep, orig, {i: crcs[i] for i in keep}))
+    from repro import tracing
+
+    tracing.enable(True)
+    try:
+        assert code.decode_bytes_batch(items) == values
+        counts = tracing.recording()["counts"]
+    finally:
+        tracing.enable(False)
+    # one fused (12, 12) product, cut into slices
+    assert counts["gf256.launch.rows.12"] == counts["gf256.slices"] > 1

@@ -6,10 +6,12 @@ would raise (unaligned blocks, VMEM overuse, programs larger than HBM). The
 topology is described inside fixtures, never while a module is imported, and
 every test skips where it cannot be described. The shapes are the main
 path's: the Emulab store's encode (5, 6), decode (6, 6), two-set fused
-decode (12, 12) and largest fused decode (128, 128), the AWS store's encode
-(4, 2), and the CDC kernel over a 1 MiB block and the largest object bucket
-(512 MiB). Both kernels' programs take and return flat buffers, as the
-wrappers move them.
+decode (12, 12) and largest fused decode (128, 128), and the AWS store's
+encode (4, 2), over a 1 MiB block and over one slice
+(``dispatch.GF_SLICE_COLS`` columns, the widest launch); and the CDC kernel
+over a 1 MiB block and over one slice (``dispatch.CDC_SLICE_BYTES``, the
+longest launch), alone and with the bytes before it as its head. Both
+kernels' programs take and return flat buffers, as the wrappers move them.
 """
 import os
 import re
@@ -19,10 +21,12 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.cdc_gearhash.kernel import gearhash_pallas
+from repro.kernels import dispatch
+from repro.kernels.cdc_gearhash.kernel import LANES, gearhash_pallas
 from repro.kernels.gf256_matmul.kernel import _round_up, gf2_bitsliced_matmul
 
 MiB = 1 << 20
+SLICE = dispatch.GF_SLICE_COLS
 HBM_BYTES = 16 * 10**9  # one v5e chip
 
 
@@ -74,8 +78,10 @@ def _fits_hbm(compiled) -> bool:
         (6, 6, 1 * MiB),       # Emulab decode
         (128, 128, 1 * MiB),   # largest block-diagonal fused decode
         (4, 2, 1 * MiB),       # AWS encode
-        (5, 6, 128 * MiB),     # Emulab encode of a whole 512 MiB object
-        (12, 12, 128 * MiB),   # two-set fused decode beside a 512 MiB object
+        (5, 6, SLICE),         # Emulab encode slice of a 128-512 MiB object
+        (6, 6, SLICE),         # Emulab decode slice
+        (12, 12, SLICE),       # two-set fused decode slice
+        (4, 2, SLICE),         # AWS encode slice
     ],
 )
 def test_gf256_kernel_compiles_for_v5e(m, k, L, one_chip, no_persistent_cache):
@@ -93,9 +99,20 @@ def test_gf256_kernel_compiles_for_v5e(m, k, L, one_chip, no_persistent_cache):
     assert _fits_hbm(compiled)
 
 
-@pytest.mark.parametrize("L", [1 * MiB, 512 * MiB])
-def test_cdc_kernel_compiles_for_v5e(L, one_chip, no_persistent_cache):
+@pytest.mark.parametrize(
+    "L,head",
+    [
+        (1 * MiB, False),                        # one block
+        (dispatch.CDC_SLICE_BYTES, False),       # a 64 MiB object, or a first slice
+        (dispatch.CDC_SLICE_BYTES, True),        # a later slice, with its head
+    ],
+)
+def test_cdc_kernel_compiles_for_v5e(L, head, one_chip, no_persistent_cache):
     data = jax.ShapeDtypeStruct((L,), jnp.uint8, sharding=one_chip)
-    compiled = _compile(lambda d: gearhash_pallas(d, mask=(1 << 19) - 1), data)
+    if head:
+        tail = jax.ShapeDtypeStruct((LANES,), jnp.uint8, sharding=one_chip)
+        compiled = _compile(lambda d, h: gearhash_pallas(d, h, mask=(1 << 19) - 1), data, tail)
+    else:
+        compiled = _compile(lambda d: gearhash_pallas(d, mask=(1 << 19) - 1), data)
     assert "tpu_custom_call" in compiled.as_text()
     assert _fits_hbm(compiled)

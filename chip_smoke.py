@@ -137,14 +137,16 @@ def make_payloads(sizes, seed: int, prefix: str = "obj") -> dict[str, bytes]:
 
 def warm_up(dss, payloads: dict, *, min_block: int, avg_block: int, n_down: int) -> dict:
     """Compile every program the steady phases run: the CDC kernel at each
-    object's length bucket, and the GF(256) kernel at each width bucket a
-    kernel-sized operand can take, up to the whole load in one batch, for
-    encode (m, k), decode (k, k), a two-set fused decode (2k, 2k) and
-    repair (n_down, k). Above a slice size (``kernels.dispatch``) these
-    calls launch, and so compile, the slices the steady phases launch."""
+    object's length bucket, and the GF(256) kernel at each width bucket up
+    to the whole load in one batch, or up to a slice
+    (``dispatch.GF_SLICE_COLS``) where that is narrower, for encode (m, k),
+    decode (k, k), a two-set fused decode (2k, 2k) and repair (n_down, k).
+    A wider call launches in slices of that width, and the batched coding
+    calls in windows of it, whose last one can take any width: so every
+    bucket from one lane row up."""
     import numpy as np
 
-    from repro.erasure.rs import AUTO_KERNEL_MIN_BYTES
+    from repro.kernels import dispatch
     from repro.kernels.cdc_gearhash.ops import boundary_bitmap
     from repro.kernels.dispatch import width_bucket
     from repro.kernels.gf256_matmul.ops import gf256_matmul
@@ -155,13 +157,12 @@ def warm_up(dss, payloads: dict, *, min_block: int, avg_block: int, n_down: int)
     # the whole load in one batch: each block adds at most one column to
     # its bytes / k, and only an object's last block is under min_block
     total = sum(len(v) for v in payloads.values())
-    top = width_bucket(total // k + total // min_block + len(payloads))
+    top = min(width_bucket(total // k + total // min_block + len(payloads)),
+              dispatch.GF_SLICE_COLS)
     shapes = [(m, k), (k, k), (2 * k, 2 * k), (n_down, k)]
     for rows, cols in shapes:
-        # the "auto" backend takes the kernel from AUTO_KERNEL_MIN_BYTES of
-        # operand, so a taller operand reaches the kernel at a narrower width
         A = np.ones((rows, cols), np.uint8)
-        w = width_bucket(-(-AUTO_KERNEL_MIN_BYTES // cols))
+        w = width_bucket(1)
         while w <= top:
             gf256_matmul(A, np.zeros((cols, w), np.uint8))
             w *= 2

@@ -31,14 +31,20 @@ into as few matmuls as possible: values are laid side by side column-wise
 (GF(256) matmul acts per column, so no per-value padding is needed), decode
 groups sharing a surviving-fragment index set share one cached inverted
 generator (``_decoder_cached``), and on the native kernel multiple groups
-fuse into ONE block-diagonal launch. Fragments carry an optional CRC-32
+fuse into ONE block-diagonal operand. That operand is never built whole: it
+is packed straight from the value or fragment bytes, multiplied and unpacked
+one column window of ``dispatch.GF_SLICE_COLS`` at a time, so no host
+buffer of a call is wider than a window (``_by_window``); a call no wider
+than one is one launch of its whole operand. Fragments carry an optional CRC-32
 computed/verified in the same traversal that materialises the bytes
 (``with_crc=True`` / a per-item crc dict), so integrity checking never costs
 a second pass over the data.
 """
 from __future__ import annotations
 
+import bisect
 import functools
+import itertools
 import zlib
 from dataclasses import dataclass, field
 
@@ -92,6 +98,47 @@ def bytes_to_rows(data: bytes, k: int) -> tuple[np.ndarray, int]:
 
 def rows_to_bytes(rows: np.ndarray, orig_len: int) -> bytes:
     return rows.reshape(-1).tobytes()[:orig_len]
+
+
+class _Columns:
+    """Ragged members laid side by side: member b holds the columns
+    ``[offsets[b], offsets[b] + widths[b])`` of one ``width``-wide operand."""
+
+    def __init__(self, widths: list[int]) -> None:
+        self.widths = widths
+        self.offsets = [0, *itertools.accumulate(widths)][:-1]
+        self.width = sum(widths)
+
+    def within(self, lo: int, hi: int):
+        """``(b, j0, j1, at)`` for each member b that meets the columns
+        ``[lo, hi)``: its own columns ``[j0, j1)`` sit at column ``at`` of
+        that window."""
+        b = bisect.bisect_right(self.offsets, lo) - 1
+        while b < len(self.widths) and self.offsets[b] < hi:
+            off = self.offsets[b]
+            j0, j1 = max(lo - off, 0), min(hi - off, self.widths[b])
+            if j1 > j0:
+                yield b, j0, j1, off + j0 - lo
+            b += 1
+
+
+def _rows_into(dst: np.ndarray, data: np.ndarray, L: int, j0: int, j1: int) -> None:
+    """Columns ``[j0, j1)`` of ``data`` as k rows of L bytes (zero past its
+    end, as ``bytes_to_rows`` pads it) into the ``(k, j1 - j0)`` ``dst``."""
+    full = len(data) // L
+    if full:
+        dst[:full] = data[: full * L].reshape(full, L)[:, j0:j1]
+    if full < len(dst):
+        tail = data[full * L + j0 : full * L + j1]
+        dst[full, : len(tail)] = tail
+        dst[full, len(tail) :] = 0
+        dst[full + 1 :] = 0
+
+
+def _row_bytes(data: np.ndarray, L: int, i: int) -> bytes:
+    """Row i of ``data`` as k rows of L bytes, zero past its end."""
+    row = data[i * L : (i + 1) * L].tobytes()
+    return row + bytes(L - len(row)) if len(row) < L else row
 
 
 @functools.lru_cache(maxsize=128)
@@ -161,15 +208,17 @@ class RSCode:
         return self._parity[idx - self.k].copy()
 
     # -- core ops ------------------------------------------------------------
-    def _use_kernel(self, A: np.ndarray, B: np.ndarray) -> bool:
-        if self.backend == "numpy" or A.size == 0 or B.size == 0:
+    def _use_kernel(self, A: np.ndarray, rows: int, cols: int) -> bool:
+        """Whether ``A (x) B`` for a ``(rows, cols)`` operand B takes the
+        kernel backend."""
+        if self.backend == "numpy" or A.size == 0 or rows * cols == 0:
             return False
         if self.backend == "kernel":
-            return B.shape[1] >= 8
-        return B.size >= AUTO_KERNEL_MIN_BYTES  # "auto"
+            return cols >= 8
+        return rows * cols >= AUTO_KERNEL_MIN_BYTES  # "auto"
 
     def _matmul(self, A: np.ndarray, B: np.ndarray) -> np.ndarray:
-        if self._use_kernel(np.asarray(A), np.asarray(B)):
+        if self._use_kernel(np.asarray(A), *np.shape(B)):
             from repro.kernels.gf256_matmul import ops as gf_ops
 
             return gf_ops.gf256_matmul(A, B)
@@ -300,43 +349,77 @@ class RSCode:
         return self.encode_bytes_batch([value], with_crc=with_crc)[0]
 
     def encode_bytes_batch(self, values: list[bytes], *, with_crc: bool = False):
-        """Batch ``encode_bytes`` over many byte strings with ONE fused matmul.
+        """Batch ``encode_bytes`` over many byte strings with fused matmuls.
 
         The values' (k, L_b) row blocks are laid side by side column-wise —
         the GF matmul acts per column, so ragged lengths fuse with NO
-        padding and the result is bit-identical to per-value encoding.
-        Returns ``[(fragments, orig_len)]`` aligned with ``values``, or
-        ``[(fragments, orig_len, crcs)]`` with ``with_crc=True`` — the CRC-32
-        of each fragment, computed in the same pass that materialises its
-        bytes (the integrity tags the EC DAP ships inside coded elements)."""
+        padding and the result is bit-identical to per-value encoding. The
+        operand is never built whole: each column window is packed straight
+        from the value bytes and multiplied on its own (``_by_window``), and
+        each value's parity is cut from the one or more window products it
+        spans. Returns ``[(fragments, orig_len)]`` aligned with ``values``,
+        or ``[(fragments, orig_len, crcs)]`` with ``with_crc=True`` — the
+        CRC-32 of each fragment, computed in the same pass that materialises
+        its bytes (the integrity tags the EC DAP ships inside coded
+        elements)."""
         if not values:
             return []
+        k, m = self.k, self.m
         with tracing.span("rs.encode"):
-            with tracing.span("rs.pack"):
-                rows: list[np.ndarray] = []
-                origs: list[int] = []
-                for v in values:
-                    r, o = bytes_to_rows(v, self.k)
-                    rows.append(r)
-                    origs.append(o)
-                if self.m:
-                    flat = rows[0] if len(rows) == 1 else np.concatenate(rows, axis=1)
-            if self.m:
-                parity = np.asarray(self._matmul(self._parity, flat))
+            data = [np.frombuffer(v, dtype=np.uint8) for v in values]
+            layout = _Columns([-(-len(d) // k) or 1 for d in data])
+            # each value's parity rows, as the pieces of the windows it spans
+            parity: list[list[list[bytes]]] = [[[] for _ in range(m)] for _ in values]
+            if m:
+                def pack(lo: int, hi: int) -> np.ndarray:
+                    B = np.empty((k, hi - lo), dtype=np.uint8)
+                    for b, j0, j1, at in layout.within(lo, hi):
+                        _rows_into(B[:, at : at + j1 - j0], data[b], layout.widths[b], j0, j1)
+                    return B
+
+                for lo, hi, product in self._by_window(self._parity, k, layout.width, pack):
+                    with tracing.span("rs.unpack"):
+                        for b, j0, j1, at in layout.within(lo, hi):
+                            for j in range(m):
+                                parity[b][j].append(product[j, at : at + j1 - j0].tobytes())
             out = []
-            off = 0
             with tracing.span("rs.unpack"):
-                for b, r in enumerate(rows):
-                    lb = r.shape[1]
-                    frags = [r[i].tobytes() for i in range(self.k)]
-                    if self.m:
-                        frags += [parity[j, off : off + lb].tobytes() for j in range(self.m)]
-                        off += lb
+                for d, L, pieces in zip(data, layout.widths, parity):
+                    frags = [_row_bytes(d, L, i) for i in range(k)]
+                    frags += [b"".join(p) for p in pieces]
                     if with_crc:
-                        out.append((frags, origs[b], [fragment_crc(f) for f in frags]))
+                        out.append((frags, len(d), [fragment_crc(f) for f in frags]))
                     else:
-                        out.append((frags, origs[b]))
+                        out.append((frags, len(d)))
             return out
+
+    def _by_window(self, A: np.ndarray, rows: int, width: int, pack):
+        """Yield ``(lo, hi, A (x) B[:, lo:hi])`` over the column windows of a
+        ``(rows, width)`` operand ``B`` that is never built whole:
+        ``pack(lo, hi)`` returns its columns ``[lo, hi)``. The windows are
+        ``dispatch.slices`` at ``dispatch.GF_SLICE_COLS``, so no operand or
+        product of a call is wider than one. The kernel-or-LUT choice is
+        made once, on the whole operand; on the kernel each window is one
+        ``gf256_matmul`` call, and where a call has several, each counts as
+        one slice of a larger call (``gf256.slices``, with its unpadded
+        bytes in ``gf256.bytes_in_sliced``)."""
+        from repro.kernels import dispatch
+
+        windows = dispatch.slices(width, dispatch.GF_SLICE_COLS)
+        kernel = self._use_kernel(A, rows, width)
+        for lo, hi in windows:
+            with tracing.span("rs.pack"):
+                B = pack(lo, hi)
+            if kernel:
+                from repro.kernels.gf256_matmul import ops as gf_ops
+
+                product = gf_ops.gf256_matmul(A, B)
+                if len(windows) > 1:
+                    tracing.count("gf256.slices")
+                    tracing.count("gf256.bytes_in_sliced", B.size)
+            else:
+                product = gf_matmul_np(A, B)
+            yield lo, hi, product
 
     def _choose_idxs(self, fragments: dict) -> tuple[int, ...]:
         """The k-subset of fragment indices to decode from: the all-systematic
@@ -348,39 +431,59 @@ class RSCode:
             return tuple(range(self.k))
         return tuple(int(i) for i in sorted(fragments)[: self.k])
 
-    def _decode_flats(
-        self, jobs: list[tuple[np.ndarray, np.ndarray]]
-    ) -> list[np.ndarray]:
-        """Run each (decoder, (k, W) operand) job; on the native kernel,
-        multiple jobs fuse into ONE block-diagonal launch (zero blocks are
-        free on the MXU; the CPU LUT path keeps one matmul per job, where a
-        block-diagonal product would cost G x the dense work)."""
+    def _decode_groups(self, groups: list[tuple[np.ndarray, list]], out: list) -> None:
+        """Decode each ``(decoder, members)`` group into ``out``; a member is
+        ``(pos, rows, L, orig)``, its k fragment rows of L bytes. A group's
+        members lie side by side as one ``(k, W)`` operand, multiplied
+        window by window. On the native kernel several groups fuse into
+        ONE block-diagonal ``(G*k, G*k)`` product over the widest group's
+        columns, each group's rows zero past its own width (zero blocks are
+        free on the MXU; the CPU LUT path keeps one product per group, where
+        a block-diagonal one would cost G x the dense work)."""
+        k = self.k
         fuse = self.fuse_groups
-        if fuse is None and self.backend != "numpy" and len(jobs) > 1:
+        if fuse is None and self.backend != "numpy" and len(groups) > 1:
             from repro.kernels import dispatch
 
             fuse = dispatch.kernel_is_native()
         if (
             not fuse
-            or len(jobs) <= 1
+            or len(groups) <= 1
             or self.backend == "numpy"
-            or len(jobs) * self.k > _FUSE_MAX_ROWS
+            or len(groups) * k > _FUSE_MAX_ROWS
         ):
-            return [np.asarray(self._matmul(dec, flat)) for dec, flat in jobs]
-        k, G = self.k, len(jobs)
-        wmax = max(flat.shape[1] for _, flat in jobs)
-        with tracing.span("rs.pack"):
-            A = np.zeros((G * k, G * k), dtype=np.uint8)
-            B = np.zeros((G * k, wmax), dtype=np.uint8)
-            for g, (dec, flat) in enumerate(jobs):
+            passes = [(dec, [members]) for dec, members in groups]
+        else:
+            A = np.zeros((len(groups) * k, len(groups) * k), dtype=np.uint8)
+            for g, (dec, _) in enumerate(groups):
                 A[g * k : (g + 1) * k, g * k : (g + 1) * k] = dec
-                B[g * k : (g + 1) * k, : flat.shape[1]] = flat
-        out = np.asarray(self._matmul(A, B))
-        with tracing.span("rs.unpack"):
-            return [
-                np.ascontiguousarray(out[g * k : (g + 1) * k, : flat.shape[1]])
-                for g, (_, flat) in enumerate(jobs)
-            ]
+            passes = [(A, [members for _, members in groups])]
+        for A, stack in passes:
+            layouts = [_Columns([L for _, _, L, _ in members]) for members in stack]
+            values = [[np.empty((k, L), dtype=np.uint8) for _, _, L, _ in members]
+                      for members in stack]
+
+            def pack(lo: int, hi: int) -> np.ndarray:
+                B = np.empty((len(stack) * k, hi - lo), dtype=np.uint8)
+                for g, (members, layout) in enumerate(zip(stack, layouts)):
+                    dst = B[g * k : (g + 1) * k]
+                    for b, j0, j1, at in layout.within(lo, hi):
+                        for r, row in enumerate(members[b][1]):
+                            dst[r, at : at + j1 - j0] = row[j0:j1]
+                    dst[:, max(layout.width - lo, 0) :] = 0
+                return B
+
+            wmax = max(layout.width for layout in layouts)
+            for lo, hi, product in self._by_window(A, len(stack) * k, wmax, pack):
+                with tracing.span("rs.unpack"):
+                    for g, layout in enumerate(layouts):
+                        for b, j0, j1, at in layout.within(lo, hi):
+                            values[g][b][:, j0:j1] = product[g * k : (g + 1) * k,
+                                                             at : at + j1 - j0]
+            with tracing.span("rs.unpack"):
+                for members, decoded in zip(stack, values):
+                    for (pos, _, _, orig), value in zip(members, decoded):
+                        out[pos] = value.reshape(-1)[:orig].tobytes()
 
     def decode_bytes_batch(self, items: list[tuple]) -> list[bytes]:
         """Decode many byte values with as few GF(256) matmuls as possible.
@@ -392,15 +495,16 @@ class RSCode:
         verify while the rows are gathered. Items whose chosen index subset
         coincides (the common case for a batched read: every block heard
         from the same quorum) share one cached inverted generator and fuse
-        column-wise into ONE matmul regardless of ragged lengths; distinct
-        subsets additionally fuse block-diagonally into a single launch on
-        the native kernel. Raises ``ValueError`` when an item's chosen
-        fragments disagree in length (a short/truncated fragment would
-        otherwise silently decode to garbage) or fail their checksum.
-        Returns the decoded bytes aligned with ``items``."""
+        column-wise into one operand regardless of ragged lengths, packed
+        and multiplied one column window at a time; distinct subsets
+        additionally fuse block-diagonally on the native kernel. Raises
+        ``ValueError`` when an item's chosen fragments disagree in length
+        (a short/truncated fragment would otherwise silently decode to
+        garbage) or fail their checksum. Returns the decoded bytes aligned
+        with ``items``."""
         out: list[bytes | None] = [None] * len(items)
         sys_idxs = tuple(range(self.k))
-        groups: dict[tuple[int, ...], list[tuple[int, dict, int, int]]] = {}
+        groups: dict[tuple[int, ...], list[tuple[int, list, int, int]]] = {}
         with tracing.span("rs.decode"):
             for pos, item in enumerate(items):
                 fragments, orig = item[0], item[1]
@@ -431,30 +535,13 @@ class RSCode:
                     with tracing.span("rs.unpack"):
                         out[pos] = b"".join(bytes(fragments[i]) for i in idxs)[:orig]
                 else:
-                    groups.setdefault(idxs, []).append((pos, fragments, L, orig))
-            jobs: list[tuple[np.ndarray, np.ndarray]] = []
-            metas: list[list[tuple[int, int, int, int]]] = []
-            with tracing.span("rs.pack"):
-                for idxs, members in groups.items():
-                    W = sum(L for _, _, L, _ in members)
-                    flat = np.zeros((self.k, W), dtype=np.uint8)
-                    meta: list[tuple[int, int, int, int]] = []
-                    off = 0
-                    for pos, fragments, L, orig in members:
-                        for r, i in enumerate(idxs):
-                            flat[r, off : off + L] = np.frombuffer(
-                                fragments[i], dtype=np.uint8
-                            )
-                        meta.append((pos, off, L, orig))
-                        off += L
-                    jobs.append((_decoder_cached(self.n, self.k, idxs), flat))
-                    metas.append(meta)
-            decoded = self._decode_flats(jobs)
-            with tracing.span("rs.unpack"):
-                for data, meta in zip(decoded, metas):
-                    for pos, off, L, orig in meta:
-                        rows = np.ascontiguousarray(data[:, off : off + L])
-                        out[pos] = rows_to_bytes(rows, orig)
+                    rows = [np.frombuffer(fragments[i], dtype=np.uint8) for i in idxs]
+                    groups.setdefault(idxs, []).append((pos, rows, L, orig))
+            self._decode_groups(
+                [(_decoder_cached(self.n, self.k, idxs), members)
+                 for idxs, members in groups.items()],
+                out,
+            )
         return out  # type: ignore[return-value]
 
     def decode_bytes(

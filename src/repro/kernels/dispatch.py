@@ -20,12 +20,19 @@ import jax
 _LANE = 128
 
 # Slice sizes, powers of two: a CDC stream longer than CDC_SLICE_BYTES is
-# hashed CDC_SLICE_BYTES at a time, and a GF(256) operand wider than
-# GF_SLICE_COLS columns is multiplied GF_SLICE_COLS columns at a time. Both
-# lie above every width the 1-64 MiB objects reach (a 64 MiB stream, a
-# 2^24-column decode of one), so those launch whole.
+# hashed CDC_SLICE_BYTES at a time (a 64 MiB stream launches whole), and a
+# GF(256) operand wider than GF_SLICE_COLS columns is multiplied
+# GF_SLICE_COLS columns at a time. RSCode's batched calls pack and unpack in
+# windows of the same width, so no host buffer of a coding call is wider.
+# 2^21 is the largest power of two that keeps every window's operand and
+# product at or under 16 MiB ((5, 6) encode 12 / 10 MiB, (4, 2) 4 / 8 MiB,
+# (6, 6) decode 12 / 12 MiB). glibc serves a request above its mmap
+# threshold, which rises with the buffers freed but never past 32 MiB, with
+# a fresh mapping that it unmaps on free, so every page of a larger buffer
+# faults again on each call: on a TPU v5e host a fresh product of 32 MiB or
+# more came back at under 0.9 GB/s, one of 16 MiB at 6.1 GB/s.
 CDC_SLICE_BYTES = 1 << 26
-GF_SLICE_COLS = 1 << 24
+GF_SLICE_COLS = 1 << 21
 
 
 def kernel_is_native() -> bool:

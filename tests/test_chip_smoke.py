@@ -68,6 +68,9 @@ def test_phases_run_end_to_end_in_interpret_mode(smoke, monkeypatch):
     # repaired blocks reach the kernel as they do on the chip, and a repair
     # shape the warm-up missed shows as a steady compile
     monkeypatch.setattr(rs, "AUTO_KERNEL_MIN_BYTES", 1 * KiB)
+    # and the slice with them (2^21 columns on the chip), so the batched
+    # calls launch in windows as they do there
+    monkeypatch.setattr(dispatch, "GF_SLICE_COLS", dispatch.GF_SLICE_COLS // 256)
     monkeypatch.setattr(gf_ops, "gf2_bitsliced_matmul",
                         _interpreted(gf_ops.gf2_bitsliced_matmul))
     monkeypatch.setattr(cdc_ops, "gearhash_pallas",

@@ -170,7 +170,7 @@ def test_flat_boundary_matches_lut(m, k, L, path, monkeypatch):
 
 
 # -- operands wider than a slice ---------------------------------------------
-SLICE = 2048  # dispatch.GF_SLICE_COLS patched down: 2^24 on the chip
+SLICE = 2048  # dispatch.GF_SLICE_COLS patched down: 2^21 on the chip
 
 
 @pytest.mark.parametrize("path", ["interpret", "jit_ref"])

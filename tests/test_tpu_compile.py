@@ -78,10 +78,10 @@ def _fits_hbm(compiled) -> bool:
         (6, 6, 1 * MiB),       # Emulab decode
         (128, 128, 1 * MiB),   # largest block-diagonal fused decode
         (4, 2, 1 * MiB),       # AWS encode
-        (5, 6, SLICE),         # Emulab encode slice of a 128-512 MiB object
-        (6, 6, SLICE),         # Emulab decode slice
-        (12, 12, SLICE),       # two-set fused decode slice
-        (4, 2, SLICE),         # AWS encode slice
+        (5, 6, SLICE),         # Emulab encode window of a 16-512 MiB object
+        (6, 6, SLICE),         # Emulab decode window
+        (12, 12, SLICE),       # two-set fused decode window
+        (4, 2, SLICE),         # AWS encode window of a 4-16 MiB object
     ],
 )
 def test_gf256_kernel_compiles_for_v5e(m, k, L, one_chip, no_persistent_cache):
